@@ -23,6 +23,10 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 os.environ.setdefault("JAX_ENABLE_X64", "1")
+# JAX's persistent compile cache: the caller's directory when set, else a
+# fixed one in the checkout (the path is part of every entry's key)
+os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", ".jax_cache"))
 
 U64MAX = np.uint64(0xFFFFFFFFFFFFFFFF)
 

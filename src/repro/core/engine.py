@@ -186,8 +186,13 @@ class ProbeEngine:
     # ------------------------------------------------------------------
     # gather
     # ------------------------------------------------------------------
-    def gather(self, state: jax.Array, lanes: jax.Array) -> jax.Array:
-        """The one fused load: every word for the batch in a single gather."""
+    @staticmethod
+    def gather(state: jax.Array, lanes: jax.Array) -> jax.Array:
+        """The one fused load: every word for the batch in a single gather.
+
+        The fused entry points take a ``gather`` override with this
+        signature: the Pallas kernels (``kernels/gather.py``) plug their
+        TPU lane gather in here and keep plan and combine as they are."""
         return state[lanes]
 
     # ------------------------------------------------------------------
@@ -346,20 +351,21 @@ class ProbeEngine:
     # jax.named_scope below is a trace-time annotation only: it adds NO
     # jaxpr equations, so the fused-probe invariants (and the jaxpr text
     # itself) are identical with observability on or off (tests/test_obs.py)
-    def range_batched(self, state: jax.Array, lo, hi) -> jax.Array:
+    def range_batched(self, state: jax.Array, lo, hi,
+                      gather=None) -> jax.Array:
         with jax.named_scope("bloomrf/plan"):
             plan = self.plan_range(lo, hi)
         with jax.named_scope("bloomrf/gather"):
-            g = self.gather(state, plan.lanes)
+            g = (gather or self.gather)(state, plan.lanes)
         with jax.named_scope("bloomrf/combine"):
             return self.combine_range(
                 g, plan, state=state if self.lay.has_exact else None)
 
-    def point_batched(self, state: jax.Array, ys) -> jax.Array:
+    def point_batched(self, state: jax.Array, ys, gather=None) -> jax.Array:
         with jax.named_scope("bloomrf/plan"):
             plan = self.plan_point(ys)
         with jax.named_scope("bloomrf/gather"):
-            g = self.gather(state, plan.lanes)
+            g = (gather or self.gather)(state, plan.lanes)
         with jax.named_scope("bloomrf/combine"):
             return self.combine_point(g, plan)
 
@@ -436,7 +442,8 @@ class StackedProbe:
         return a[:, r0:r1]
 
     # -- fused probes ------------------------------------------------------
-    def _range_all(self, flat_state: jax.Array, lo, hi) -> jax.Array:
+    def _range_all(self, flat_state: jax.Array, lo, hi,
+                   gather=None) -> jax.Array:
         lo = jnp.atleast_1d(jnp.asarray(lo))
         hi = jnp.atleast_1d(jnp.asarray(hi))
         B = lo.shape[0]
@@ -455,7 +462,8 @@ class StackedProbe:
                 parts.append(shifted.reshape(B, -1))
                 plans.append(plan)
         with jax.named_scope("bloomrf/gather"):
-            g = flat_state[jnp.concatenate(parts, axis=-1)]  # the one gather
+            g = (gather or ProbeEngine.gather)(      # the one gather
+                flat_state, jnp.concatenate(parts, axis=-1))
         with jax.named_scope("bloomrf/combine"):
             out, off = [], 0
             for (e, r0, r1), plan in zip(self.spans, plans):
@@ -465,7 +473,8 @@ class StackedProbe:
                 out.append(e.combine_range(gg, plan))
             return jnp.concatenate(out, axis=-1)          # (B, R)
 
-    def _point_all(self, flat_state: jax.Array, ys) -> jax.Array:
+    def _point_all(self, flat_state: jax.Array, ys,
+                   gather=None) -> jax.Array:
         ys = jnp.atleast_1d(jnp.asarray(ys))
         B = ys.shape[0]
         with jax.named_scope("bloomrf/plan"):
@@ -478,7 +487,8 @@ class StackedProbe:
                 parts.append(shifted.reshape(B, -1))
                 plans.append(plan)
         with jax.named_scope("bloomrf/gather"):
-            g = flat_state[jnp.concatenate(parts, axis=-1)]  # the one gather
+            g = (gather or ProbeEngine.gather)(      # the one gather
+                flat_state, jnp.concatenate(parts, axis=-1))
         with jax.named_scope("bloomrf/combine"):
             out, off = [], 0
             for (e, r0, r1), plan in zip(self.spans, plans):
@@ -490,7 +500,7 @@ class StackedProbe:
             return jnp.concatenate(out, axis=-1)          # (B, R)
 
     def _touch_all(self, flat_state: jax.Array, kmin, kmax, lo, hi,
-                   quarantine=None):
+                   quarantine=None, gather=None):
         """Fence-fused range probe: the full store scan-pruning plane.
 
         ``kmin``/``kmax`` are per-row key fences (shape ``(R,)``, key
@@ -514,7 +524,7 @@ class StackedProbe:
         kmax = jnp.asarray(kmax, lo.dtype)
         fence = ((hi[:, None] >= kmin[None, :])
                  & (lo[:, None] <= kmax[None, :]))
-        filt = self._range_all(flat_state, lo, hi)
+        filt = self._range_all(flat_state, lo, hi, gather)
         if quarantine is not None:
             filt = filt | jnp.asarray(quarantine, bool)[None, :]
         return fence, fence & filt

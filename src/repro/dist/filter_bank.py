@@ -40,7 +40,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 from ..core import BloomRF, basic_layout, stacked_probe
@@ -218,7 +217,7 @@ class ShardedFilterBank:
             local = hits.any(axis=0)
             return jax.lax.psum(local.astype(jnp.int32), axis) > 0
 
-        smap = functools.partial(shard_map, mesh=mesh, check_rep=False)
+        smap = functools.partial(jax.shard_map, mesh=mesh, check_vma=False)
         self._insert = jax.jit(smap(
             sm_insert, in_specs=(spec_state, PS(), PS()),
             out_specs=spec_state))

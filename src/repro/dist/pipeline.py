@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as PS
 
 __all__ = ["pipeline_apply"]
@@ -68,6 +67,6 @@ def pipeline_apply(stage_fn, params, x, mesh, axis: str,
         res = jnp.where(is_last, out_buf, jnp.zeros_like(out_buf))
         return jax.lax.psum(res, axis)
 
-    fn = shard_map(run, mesh=mesh, in_specs=(PS(axis), PS()), out_specs=PS(),
-                   check_rep=False)
+    fn = jax.shard_map(run, mesh=mesh, in_specs=(PS(axis), PS()),
+                       out_specs=PS(), check_vma=False)
     return fn(params, x).reshape(x.shape)

@@ -61,7 +61,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 from ..core import (BloomRF, Generations, basic_layout, dyadic_prefixes,
@@ -459,6 +458,7 @@ class ShardedTenantFilterBank:
         self.n_replicas = int(mesh.shape[replica_axis]) if replica_axis else 1
         self.tenants_per_dev = tbank.n_tenants // n_data
         self.state_sharding = NamedSharding(mesh, PS(data_axis, None, None))
+        self._replicated = NamedSharding(mesh, PS())
 
         bank = tbank.bank
         tpd = self.tenants_per_dev
@@ -537,7 +537,7 @@ class ShardedTenantFilterBank:
             local = jax.vmap(per_tenant)(t_ids, st, mst).any(axis=(0, 1))
             return jax.lax.psum(local.astype(jnp.int32), data_axis) > 0
 
-        smap = functools.partial(shard_map, mesh=mesh, check_rep=False)
+        smap = functools.partial(jax.shard_map, mesh=mesh, check_vma=False)
         self._insert = jax.jit(smap(
             sm_insert, in_specs=(spec_state, bspec, bspec, bspec),
             out_specs=spec_state))
@@ -580,6 +580,14 @@ class ShardedTenantFilterBank:
                     for a in arrs]
         return tenants, arrs, n
 
+    def _unpad(self, out, n: int):
+        """Drop the replica padding.  Verdicts come back split over the
+        replica axis; on a mesh with explicit axes a slice of that split
+        is ambiguous, so gather them onto every device first."""
+        if out.shape[0] == n:
+            return out
+        return jax.device_put(out, self._replicated)[:n]
+
     # -- public API (mirrors TenantFilterBank) -----------------------------
     def insert(self, state, tenants, keys):
         tenants = jnp.asarray(tenants, jnp.uint32)
@@ -603,7 +611,7 @@ class ShardedTenantFilterBank:
         tenants = jnp.asarray(tenants, jnp.uint32)
         low, shard = self.tbank.bank._route(qs)
         tenants, (low, shard), n = self._pad(tenants, [low, shard])
-        return self._point(state, low, shard, tenants)[:n]
+        return self._unpad(self._point(state, low, shard, tenants), n)
 
     def range(self, state, tenants, lo, hi, meta=None):
         tenants = jnp.asarray(tenants, jnp.uint32)
@@ -612,5 +620,5 @@ class ShardedTenantFilterBank:
         tenants, routed, n = self._pad(
             tenants, [lo_low, lo_shard, hi_low, hi_shard])
         if meta is None:
-            return self._range(state, *routed, tenants)[:n]
-        return self._range_meta(state, meta, *routed, tenants)[:n]
+            return self._unpad(self._range(state, *routed, tenants), n)
+        return self._unpad(self._range_meta(state, meta, *routed, tenants), n)

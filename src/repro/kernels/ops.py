@@ -1,17 +1,20 @@
 """Jit'd dispatch wrappers over the Pallas kernels with XLA fallbacks.
 
-``interpret`` defaults to True off-TPU (kernel bodies execute in Python on
-CPU for validation); on a real TPU backend pass ``interpret=False``.
+``interpret`` defaults to "interpret only off TPU": kernels run compiled
+on a TPU backend and in the Pallas interpreter elsewhere (validation).
 
 The resident/partitioned dispatch threshold is a config knob (DESIGN.md
 §3): filters of up to ``vmem_budget_u32`` lanes take the VMEM-resident
-kernels, larger ones the block-partitioned kernels.  The default comes
+kernels, larger ones the partitioned kernels.  The default comes
 from the ``BLOOMRF_VMEM_BUDGET_U32`` environment variable (validated every
 time it is read: non-integer or <= 0 raises a ``ValueError`` naming the
-variable) and falls back to 2^22 lanes = 16 MiB — a comfortable resident
-footprint on a v5e core.  Deployments with other VMEM sizes, or tests
-that want to force the partitioned path, set the env var or pass
-``vmem_budget_u32`` explicitly.
+variable) and falls back to 2^21 lanes = 8 MiB.  That is half the TPU
+compiler's default 16 MiB scoped-VMEM limit on a v5e core: a resident
+state is one unpipelined VMEM copy, and the other half holds the
+double-buffered probe tiles (2^22 lanes alone exceed the limit).  The
+partitioned tier keeps the state in HBM and DMAs the probed rows.
+Deployments with other VMEM sizes, or tests that want to force the
+partitioned path, set the env var or pass ``vmem_budget_u32`` explicitly.
 """
 from __future__ import annotations
 
@@ -27,6 +30,7 @@ from ..obs import trace as _obs_trace
 from . import insert as _insert
 from . import probe as _probe
 from . import rangeprobe as _rangeprobe
+from .gather import resolve_interpret
 from .ref import check_kernel_layout
 
 
@@ -37,8 +41,8 @@ def _tick(tier: str) -> None:
 
 __all__ = ["FilterOps", "DEFAULT_VMEM_BUDGET_U32", "read_vmem_budget_u32"]
 
-#: fallback resident/partitioned threshold in uint32 lanes (16 MiB of lanes)
-DEFAULT_VMEM_BUDGET_U32 = 1 << 22
+#: fallback resident/partitioned threshold in uint32 lanes (8 MiB of lanes)
+DEFAULT_VMEM_BUDGET_U32 = 1 << 21
 
 
 def read_vmem_budget_u32() -> int:
@@ -63,16 +67,12 @@ def read_vmem_budget_u32() -> int:
     return val
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 class FilterOps:
     """Layout-bound kernel dispatcher.
 
     * small filters (<= ``vmem_budget_u32`` lanes) -> VMEM-resident kernels;
-    * large filters -> block-partitioned point AND range probe kernels
-      (HBM-scale filters no longer fall back to XLA for range queries);
+    * large filters -> partitioned point AND range probe kernels (state
+      in HBM, probed rows DMA'd into VMEM); inserts take the XLA path;
     * exact-layer layouts (range) -> XLA engine path (dynamic bounded scan);
     * same-layout run *stacks* (``point_stacked``/``range_stacked``) ->
       the stacked-resident kernel while the whole (R, total_u32) stack fits
@@ -91,7 +91,7 @@ class FilterOps:
         check_kernel_layout(layout)
         self.layout = layout
         self.filter = BloomRF(layout, _warn=False)
-        self.interpret = (not _on_tpu()) if interpret is None else interpret
+        self.interpret = resolve_interpret(interpret)
         self.vmem_budget_u32 = (read_vmem_budget_u32()
                                 if vmem_budget_u32 is None else vmem_budget_u32)
         self.resident = layout.total_u32 <= self.vmem_budget_u32

@@ -1,22 +1,19 @@
 """Pallas TPU kernels: batched bloomRF point probes.
 
-Two variants (DESIGN.md §3 — HBM->VMEM adaptation of the paper's
-cache-line-word design):
+Each probe is the engine's plan -> gather -> combine (core/engine.py,
+DESIGN.md §9) with the one fused gather routed through the Pallas lane
+gather (``kernels/gather.py``); plan and combine stay the engine's own
+XLA arithmetic, so verdicts are bit-identical to the XLA path.
 
-* ``point_probe_resident`` — the whole filter is pinned in VMEM (BlockSpec
-  maps the full state to every grid step); the grid tiles the query batch.
-  This is the fast path for per-SST/per-segment filters (a 2M-key, 16 bit/key
-  filter is 4 MiB — fits v5e VMEM comfortably).
+* ``point_probe_resident`` — the whole filter is pinned in VMEM; the grid
+  walks probe tiles.  The fast path for per-SST/per-segment filters.
+* ``point_probe_partitioned`` — HBM-scale filters: the state stays in HBM
+  and each probe's 512-byte row is DMA'd into VMEM (the gather's hbm
+  tier).
+* ``point_probe_stacked_resident`` — R same-layout rows (an LSM run
+  stack) answered by the multi-filter stacked plan, one gather per call.
 
-* ``point_probe_partitioned`` — HBM-scale filters: probes are pre-bucketed by
-  filter *block* (XLA argsort), padded to tile multiples, and the kernel walks
-  (tile, block) pairs with the block DMA'd into VMEM via a scalar-prefetched
-  index map.  This is the Putze-style cache partitioning re-targeted at the
-  TPU memory hierarchy.
-
-All kernel arithmetic is uint32 (d <= 32 sub-domains).  The per-key probe
-math is the *core* implementation itself, traced inside the kernel — the
-kernels add memory orchestration, not new math.
+All kernel arithmetic is uint32 (d <= 32 sub-domains).
 """
 from __future__ import annotations
 
@@ -24,11 +21,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from ..core import BloomRF, FilterLayout
 from ..core.engine import stacked_probe
+from .gather import gather_lanes, probe_tile
 from .ref import check_kernel_layout
 
 __all__ = [
@@ -36,97 +32,38 @@ __all__ = [
     "point_probe_partitioned",
     "point_probe_stacked_resident",
     "DEFAULT_TILE",
-    "DEFAULT_BLOCK_U32",
 ]
 
 DEFAULT_TILE = 512           # queries per grid step
-DEFAULT_BLOCK_U32 = 16384    # 64 KiB filter blocks for the partitioned path
 
 
-def _round_up(x: int, m: int) -> int:
-    return (x + m - 1) // m * m
-
-
-def _bucket_probes(lane: jax.Array, tile: int, block_u32: int, nblocks: int):
-    """Bucket flat lane probes by filter block for the partitioned kernels.
-
-    Sorts probes by owning block and pads each block's probe list to a tile
-    multiple so no kernel tile spans two blocks.  Returns ``(order, slot,
-    lane_b, tile_block, capr)``: the sort permutation, each sorted probe's
-    destination slot, the padded lane table (-1 = padding), the per-tile
-    block id (scalar prefetch input), and the padded length.  Callers
-    scatter their per-probe payloads with ``.at[slot].set(payload[order])``.
-    Shared by the point and range partitioned kernels — the padding
-    invariants live here once."""
-    nprobe = lane.shape[0]
-    blk = lane // block_u32
-    order = jnp.argsort(blk)
-    lane_s, blk_s = lane[order], blk[order]
-    counts = jnp.bincount(blk_s, length=nblocks)
-    padded_counts = ((counts + tile - 1) // tile) * tile
-    starts = jnp.concatenate([jnp.zeros(1, padded_counts.dtype),
-                              jnp.cumsum(padded_counts)])[:-1]
-    rank = jnp.arange(nprobe) - jnp.cumsum(
-        jnp.concatenate([jnp.zeros(1, counts.dtype), counts]))[:-1][blk_s]
-    slot = starts[blk_s] + rank
-    capr = _round_up(nprobe + nblocks * tile, tile)  # worst-case padding
-    lane_b = jnp.full(capr, -1, jnp.int32).at[slot].set(lane_s)
-    tile_block = jnp.where(lane_b[::tile] < 0, 0,
-                           lane_b[::tile] // block_u32).astype(jnp.int32)
-    return order, slot, lane_b, tile_block, capr
-
-
-# ---------------------------------------------------------------------------
-# resident variant
-# ---------------------------------------------------------------------------
-
-def _resident_kernel(keys_ref, state_ref, out_ref, *, filt: BloomRF):
-    # plan->gather->combine engine traced over the tile: one fused gather
-    out_ref[...] = filt.engine.point_batched(state_ref[...], keys_ref[...])
+def _gather(width: int, tile: int, resident: bool, interpret):
+    """The engine's ``gather`` hook bound to one kernel tier."""
+    return functools.partial(gather_lanes, resident=resident,
+                             tile=probe_tile(tile, width, resident),
+                             interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 3, 4))
 def point_probe_resident(layout: FilterLayout, state: jax.Array, keys,
-                         tile: int = DEFAULT_TILE, interpret: bool = True):
+                         tile: int = DEFAULT_TILE, interpret=None):
     """Batched point probe with the filter resident in VMEM."""
     check_kernel_layout(layout)
     filt = BloomRF(layout, _warn=False)
     keys = jnp.asarray(keys, jnp.uint32)
-    B = keys.shape[0]
-    Bp = _round_up(max(B, 1), tile)
-    keys_p = jnp.pad(keys, (0, Bp - B))
-    grid = (Bp // tile,)
-    out = pl.pallas_call(
-        functools.partial(_resident_kernel, filt=filt),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile,), lambda t: (t,)),
-            pl.BlockSpec((layout.total_u32,), lambda t: (0,)),  # pinned
-        ],
-        out_specs=pl.BlockSpec((tile,), lambda t: (t,)),
-        out_shape=jax.ShapeDtypeStruct((Bp,), jnp.bool_),
-        interpret=interpret,
-    )(keys_p, state)
-    return out[:B]
-
-
-# ---------------------------------------------------------------------------
-# stacked-run variant (LSM run stacks: R same-layout filter rows in VMEM)
-# ---------------------------------------------------------------------------
-
-def _stacked_kernel(keys_ref, state_ref, out_ref, *, probe):
-    out_ref[...] = probe._point_all(state_ref[...].reshape(-1), keys_ref[...])
+    return filt.engine.point_batched(
+        state, keys, gather=_gather(filt._probes_per_key, tile, True, interpret))
 
 
 @functools.partial(jax.jit, static_argnums=(0, 3, 4))
 def point_probe_stacked_resident(layout: FilterLayout, stack: jax.Array,
                                  keys, tile: int = DEFAULT_TILE,
-                                 interpret: bool = True):
+                                 interpret=None):
     """Batched point probe over a ``uint32[R, total_u32]`` run stack.
 
-    Each grid step answers one query tile against all R rows at once via
-    the multi-filter stacked plan (``core.engine.StackedProbe`` — one
-    fused gather per tile).  Returns ``bool[B, R]``."""
+    One query tile is answered against all R rows at once via the
+    multi-filter stacked plan (``core.engine.StackedProbe`` — one fused
+    gather per call).  Returns ``bool[B, R]``."""
     check_kernel_layout(layout)
     if layout.has_exact:
         raise ValueError("exact-layer layouts use the XLA path (ops.py)")
@@ -134,87 +71,19 @@ def point_probe_stacked_resident(layout: FilterLayout, stack: jax.Array,
     probe = stacked_probe((layout,) * R,
                           tuple(r * layout.total_u32 for r in range(R)))
     keys = jnp.asarray(keys, jnp.uint32)
-    B = keys.shape[0]
-    Bp = _round_up(max(B, 1), tile)
-    keys_p = jnp.pad(keys, (0, Bp - B))
-    out = pl.pallas_call(
-        functools.partial(_stacked_kernel, probe=probe),
-        grid=(Bp // tile,),
-        in_specs=[
-            pl.BlockSpec((tile,), lambda t: (t,)),
-            pl.BlockSpec((R, layout.total_u32), lambda t: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile, R), lambda t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((Bp, R), jnp.bool_),
-        interpret=interpret,
-    )(keys_p, stack)
-    return out[:B]
+    width = R * BloomRF(layout, _warn=False)._probes_per_key
+    return probe._point_all(stack.reshape(-1), keys,
+                            gather=_gather(width, tile, True, interpret))
 
 
-# ---------------------------------------------------------------------------
-# partitioned variant (HBM-scale filters)
-# ---------------------------------------------------------------------------
-
-def _partitioned_kernel(tile_block, lane_ref, sh_ref, block_ref, out_ref, *,
-                        block_u32: int):
-    del tile_block  # consumed by the index maps
-    lane = lane_ref[...]                      # global lane ids, -1 = padding
-    sh = sh_ref[...]
-    local = jnp.where(lane < 0, 0, lane % block_u32).astype(jnp.int32)
-    word = block_ref[...][local]
-    bit = (word >> sh.astype(jnp.uint32)) & jnp.uint32(1)
-    out_ref[...] = jnp.where(lane < 0, jnp.uint32(1), bit)  # pad -> neutral
-
-
-@functools.partial(jax.jit, static_argnums=(0, 3, 4, 5))
+@functools.partial(jax.jit, static_argnums=(0, 3, 4))
 def point_probe_partitioned(layout: FilterLayout, state: jax.Array, keys,
-                            tile: int = DEFAULT_TILE,
-                            block_u32: int = DEFAULT_BLOCK_U32,
-                            interpret: bool = True):
-    """Batched point probe for filters too large for VMEM.
-
-    XLA side: expand keys to probes, sort probes by filter block, pad each
-    block's probe list to a tile multiple.  Pallas side: walk tiles with the
-    owning block scalar-prefetch-mapped into VMEM.  Probe bits are then
-    AND-reduced per key (segment reduction) back in XLA.
-    """
+                            tile: int = DEFAULT_TILE, interpret=None):
+    """Batched point probe for filters too large for VMEM: the engine's
+    plan and combine in XLA around the hbm-tier lane gather."""
     check_kernel_layout(layout)
     filt = BloomRF(layout, _warn=False)
     keys = jnp.asarray(keys, jnp.uint32)
-    B = keys.shape[0]
-    U = layout.total_u32
-    nblocks = _round_up(U, block_u32) // block_u32
-    state_p = jnp.pad(state, (0, nblocks * block_u32 - U))
-
-    plan = filt.engine.plan_point(keys)                 # lanes/sh (B, P)
-    P = plan.lanes.shape[1]
-    lane = plan.lanes.reshape(-1)                       # (B*P,)
-    sh = plan.sh.astype(jnp.int32).reshape(-1)
-    keyid = jnp.repeat(jnp.arange(B, dtype=jnp.int32), P)
-
-    order, slot, lane_b, tile_block, capr = _bucket_probes(
-        lane, tile, block_u32, nblocks)
-    sh_b = jnp.zeros(capr, jnp.int32).at[slot].set(sh[order])
-    key_b = jnp.full(capr, B, jnp.int32).at[slot].set(keyid[order])  # B=scrap
-
-    ntiles = capr // tile
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(ntiles,),
-        in_specs=[
-            pl.BlockSpec((tile,), lambda t, tb: (t,)),
-            pl.BlockSpec((tile,), lambda t, tb: (t,)),
-            pl.BlockSpec((block_u32,), lambda t, tb: (tb[t],)),
-        ],
-        out_specs=pl.BlockSpec((tile,), lambda t, tb: (t,)),
-    )
-    bits = pl.pallas_call(
-        functools.partial(_partitioned_kernel, block_u32=block_u32),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((capr,), jnp.uint32),
-        interpret=interpret,
-    )(tile_block, lane_b, sh_b, state_p)
-
-    # AND-reduce per key: min of bits (1 = set) over each key's probes
-    acc = jnp.ones(B + 1, jnp.uint32).at[key_b].min(bits)
-    return acc[:B] == 1
+    return filt.engine.point_batched(
+        state, keys,
+        gather=_gather(filt._probes_per_key, tile, False, interpret))

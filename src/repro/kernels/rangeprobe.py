@@ -1,28 +1,24 @@
 """Pallas TPU kernels: batched bloomRF range probes.
 
-Both variants trace the plan->gather->combine engine (core/engine.py,
-DESIGN.md §9) instead of vmapping the scalar reference path: the per-tile
-word table is one fused ``state[lanes]`` gather of shape ``(tile, A)`` with
+Each variant runs the plan->gather->combine engine (core/engine.py,
+DESIGN.md §9) with its one fused gather — the ``(B, A)`` word table,
 covering-bit loads deduped against the child-word loads (4 word loads per
-layer per replica), and the combine phase is pure vector work on registers.
+layer per replica) — routed through the Pallas lane gather
+(``kernels/gather.py``).  Plan and combine are the engine's own XLA
+arithmetic, so verdicts are bit-identical to the XLA path by construction
+(same plan, same words, same combine).
 
-* ``range_probe_resident`` — the whole filter is pinned in VMEM (BlockSpec
-  maps the full state to every grid step); the grid tiles the query batch.
-
+* ``range_probe_resident`` — the whole filter is pinned in VMEM.
 * ``range_probe_partitioned`` — HBM-scale filters, mirroring
-  ``point_probe_partitioned``: the engine's *plan* runs in XLA and flattens
-  to ``B * A`` lane probes, which are pre-bucketed by filter block
-  (argsort), padded so no tile spans two blocks, and walked by a kernel
-  with the owning block scalar-prefetch-DMA'd into VMEM.  Gathered lane
-  values are scattered back into the ``(B, A)`` word matrix and the
-  engine's *combine* finishes in XLA — verdicts are bit-identical to the
-  resident kernel and the XLA path by construction (same plan, same words,
-  same combine).
+  ``point_probe_partitioned``: the state stays in HBM, rows DMA'd per
+  probe.
+* ``range_probe_stacked_resident`` — R same-layout rows in VMEM, one
+  gather for all rows.
 
-Layout restrictions for both kernel paths: no exact segment (its bounded
+Layout restrictions for the kernel paths: no exact segment (its bounded
 lane scan is a dynamic while_loop — fine for XLA, not for a TPU kernel);
 everything else (variable Δ, replicas, multi-segment) is supported.
-Exact-layer layouts fall back to the XLA path in ``ops.py``.
+Exact-layer layouts take the XLA path in ``ops.py``.
 """
 from __future__ import annotations
 
@@ -30,12 +26,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from ..core import BloomRF, FilterLayout
 from ..core.engine import stacked_probe
-from .probe import DEFAULT_BLOCK_U32, _bucket_probes
+from .probe import _gather
 from .ref import check_kernel_layout
 
 __all__ = ["range_probe_resident", "range_probe_partitioned",
@@ -44,165 +38,51 @@ __all__ = ["range_probe_resident", "range_probe_partitioned",
 DEFAULT_TILE = 512
 
 
-def _round_up(x: int, m: int) -> int:
-    return (x + m - 1) // m * m
-
-
 def _check_range_kernel_layout(layout: FilterLayout) -> None:
     check_kernel_layout(layout)
     if layout.has_exact:
         raise ValueError("exact-layer layouts use the XLA path (ops.py)")
 
 
-# ---------------------------------------------------------------------------
-# resident variant
-# ---------------------------------------------------------------------------
-
-def _range_kernel(lo_ref, hi_ref, state_ref, out_ref, *, filt: BloomRF):
-    out_ref[...] = filt.engine.range_batched(state_ref[...], lo_ref[...],
-                                             hi_ref[...])
-
-
 @functools.partial(jax.jit, static_argnums=(0, 4, 5))
 def range_probe_resident(layout: FilterLayout, state: jax.Array, lo, hi,
-                         tile: int = DEFAULT_TILE, interpret: bool = True):
+                         tile: int = DEFAULT_TILE, interpret=None):
     """Batched range probe with the filter resident in VMEM."""
     _check_range_kernel_layout(layout)
-    filt = BloomRF(layout, _warn=False)
-    lo = jnp.asarray(lo, jnp.uint32)
-    hi = jnp.asarray(hi, jnp.uint32)
-    B = lo.shape[0]
-    Bp = _round_up(max(B, 1), tile)
-    lo_p = jnp.pad(lo, (0, Bp - B))
-    hi_p = jnp.pad(hi, (0, Bp - B))
-    grid = (Bp // tile,)
-    out = pl.pallas_call(
-        functools.partial(_range_kernel, filt=filt),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile,), lambda t: (t,)),
-            pl.BlockSpec((tile,), lambda t: (t,)),
-            pl.BlockSpec((layout.total_u32,), lambda t: (0,)),
-        ],
-        out_specs=pl.BlockSpec((tile,), lambda t: (t,)),
-        out_shape=jax.ShapeDtypeStruct((Bp,), jnp.bool_),
-        interpret=interpret,
-    )(lo_p, hi_p, state)
-    return out[:B]
-
-
-# ---------------------------------------------------------------------------
-# stacked-run variant (LSM run stacks: R same-layout filter rows in VMEM)
-# ---------------------------------------------------------------------------
-
-def _range_stacked_kernel(lo_ref, hi_ref, state_ref, out_ref, *, probe):
-    # the StackedProbe's one fused gather, traced over the query tile:
-    # verdicts for every run row of the tile in a single (tile, R*A) load
-    out_ref[...] = probe._range_all(state_ref[...].reshape(-1),
-                                    lo_ref[...], hi_ref[...])
+    eng = BloomRF(layout, _warn=False).engine
+    return eng.range_batched(
+        state, jnp.asarray(lo, jnp.uint32), jnp.asarray(hi, jnp.uint32),
+        gather=_gather(eng.range_gather_width, tile, True, interpret))
 
 
 @functools.partial(jax.jit, static_argnums=(0, 4, 5))
 def range_probe_stacked_resident(layout: FilterLayout, stack: jax.Array,
                                  lo, hi, tile: int = DEFAULT_TILE,
-                                 interpret: bool = True):
+                                 interpret=None):
     """Batched range probe over a stack of R same-layout filter rows.
 
     ``stack`` is ``uint32[R, total_u32]`` (one row per LSM run / tenant);
-    the whole stack is pinned in VMEM and each grid step answers one query
-    tile against **all** rows at once through the multi-filter stacked plan
-    (``core.engine.StackedProbe`` — one fused gather per tile).  Returns
+    the whole stack is pinned in VMEM and each query is answered against
+    **all** rows at once through the multi-filter stacked plan
+    (``core.engine.StackedProbe`` — one fused gather per call).  Returns
     ``bool[B, R]``."""
     _check_range_kernel_layout(layout)
     R = stack.shape[0]
     probe = stacked_probe((layout,) * R,
                           tuple(r * layout.total_u32 for r in range(R)))
-    lo = jnp.asarray(lo, jnp.uint32)
-    hi = jnp.asarray(hi, jnp.uint32)
-    B = lo.shape[0]
-    Bp = _round_up(max(B, 1), tile)
-    lo_p = jnp.pad(lo, (0, Bp - B))
-    hi_p = jnp.pad(hi, (0, Bp - B))
-    out = pl.pallas_call(
-        functools.partial(_range_stacked_kernel, probe=probe),
-        grid=(Bp // tile,),
-        in_specs=[
-            pl.BlockSpec((tile,), lambda t: (t,)),
-            pl.BlockSpec((tile,), lambda t: (t,)),
-            pl.BlockSpec((R, layout.total_u32), lambda t: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile, R), lambda t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((Bp, R), jnp.bool_),
-        interpret=interpret,
-    )(lo_p, hi_p, stack)
-    return out[:B]
+    return probe._range_all(
+        stack.reshape(-1), jnp.asarray(lo, jnp.uint32),
+        jnp.asarray(hi, jnp.uint32),
+        gather=_gather(probe.range_gather_width, tile, True, interpret))
 
 
-# ---------------------------------------------------------------------------
-# partitioned variant (HBM-scale filters)
-# ---------------------------------------------------------------------------
-
-def _gather_block_kernel(tile_block, lane_ref, block_ref, out_ref, *,
-                         block_u32: int):
-    del tile_block  # consumed by the index maps
-    lane = lane_ref[...]                      # global lane ids, -1 = padding
-    local = jnp.where(lane < 0, 0, lane % block_u32).astype(jnp.int32)
-    word = block_ref[...][local]
-    out_ref[...] = jnp.where(lane < 0, jnp.uint32(0), word)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 4, 5, 6))
+@functools.partial(jax.jit, static_argnums=(0, 4, 5))
 def range_probe_partitioned(layout: FilterLayout, state: jax.Array, lo, hi,
-                            tile: int = DEFAULT_TILE,
-                            block_u32: int = DEFAULT_BLOCK_U32,
-                            interpret: bool = True):
-    """Batched range probe for filters too large for VMEM.
-
-    XLA side: run the engine's plan (pure arithmetic -> the (B, A) lane
-    table), flatten to lane probes, sort probes by filter block, pad each
-    block's probe list to a tile multiple.  Pallas side: walk tiles with the
-    owning block scalar-prefetch-mapped into VMEM, emitting the gathered
-    lane *values*.  XLA side again: scatter values back to the (B, A) word
-    matrix and run the engine's combine.
-    """
+                            tile: int = DEFAULT_TILE, interpret=None):
+    """Batched range probe for filters too large for VMEM: the engine's
+    plan and combine in XLA around the hbm-tier lane gather."""
     _check_range_kernel_layout(layout)
-    filt = BloomRF(layout, _warn=False)
-    eng = filt.engine
-    lo = jnp.asarray(lo, jnp.uint32)
-    hi = jnp.asarray(hi, jnp.uint32)
-    B = lo.shape[0]
-    U = layout.total_u32
-    nblocks = _round_up(U, block_u32) // block_u32
-    state_p = jnp.pad(state, (0, nblocks * block_u32 - U))
-
-    plan = eng.plan_range(lo, hi)
-    A = plan.lanes.shape[-1]
-    nprobe = B * A
-    lane = plan.lanes.reshape(-1)                       # (B*A,)
-    flat = jnp.arange(nprobe, dtype=jnp.int32)          # original matrix slot
-
-    order, slot, lane_b, tile_block, capr = _bucket_probes(
-        lane, tile, block_u32, nblocks)
-    flat_b = jnp.full(capr, nprobe, jnp.int32).at[slot].set(flat[order])
-
-    ntiles = capr // tile
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(ntiles,),
-        in_specs=[
-            pl.BlockSpec((tile,), lambda t, tb: (t,)),
-            pl.BlockSpec((block_u32,), lambda t, tb: (tb[t],)),
-        ],
-        out_specs=pl.BlockSpec((tile,), lambda t, tb: (t,)),
-    )
-    vals = pl.pallas_call(
-        functools.partial(_gather_block_kernel, block_u32=block_u32),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((capr,), jnp.uint32),
-        interpret=interpret,
-    )(tile_block, lane_b, state_p)
-
-    # scatter gathered words back into the (B, A) matrix; padding -> scrap
-    g = jnp.zeros(nprobe + 1, jnp.uint32).at[flat_b].set(vals)
-    g = g[:-1].reshape(B, A)
-    return eng.combine_range(g, plan)
+    eng = BloomRF(layout, _warn=False).engine
+    return eng.range_batched(
+        state, jnp.asarray(lo, jnp.uint32), jnp.asarray(hi, jnp.uint32),
+        gather=_gather(eng.range_gather_width, tile, False, interpret))

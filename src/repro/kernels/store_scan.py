@@ -1,40 +1,26 @@
-"""Pallas TPU megakernel: the LSM store scan-pruning plane in ONE kernel.
+"""The LSM store's scan-pruning plane: one jit, one ``pallas_call``.
 
-``Store.scan_many`` used to round-trip host Python between four device
-steps: the StackedProbe plan, the one fused gather over all live runs'
-filter blocks, the combine/mask algebra, and the min/max fence masking
-(computed separately in numpy).  This kernel fuses the whole plane —
-fence compare, plan, gather, combine, touch masking — into a single
-``pallas_call`` per scan batch with a flash-decoding-style grid:
+``Store.scan_many`` needs, per scan batch, the ``(B, R)`` fence and touch
+masks over every live run: min/max fence compare, the StackedProbe plan
+over all runs' filter blocks, the one fused gather, the combine, and the
+touch masking.  This module runs that whole plane on the device in one
+jitted call whose only kernel is the Pallas lane gather
+(``kernels/gather.py``); fence compare, plan and combine are the
+engine's vector arithmetic, which XLA fuses around it.
 
-* the **query axis** is tiled as usual (``tile`` queries per step);
-* the **run axis** is split into *blocks* of ``runs_per_block`` stacked
-  filter rows, the way flash decoding splits KV into chunks — each
-  ``(query_tile, run_block)`` grid step answers one tile against one
-  block of runs and writes a disjoint output sub-matrix, so no
-  cross-block combine is needed.  The per-block filter state is DMA'd
-  HBM -> VMEM by the BlockSpec pipeline, which double-buffers the next
-  block's transfer behind the current block's compute (the standard
-  Pallas grid pipeline); a store whose whole run stack exceeds the VMEM
-  budget still scans with every filter block streamed exactly once per
-  query tile.
+* Run rows are padded to one uniform ``rowpad`` lane width
+  (:func:`build_run_stack`) and planned at bases ``r * rowpad``; rows may
+  mix capacity classes (the normal LSM case) — the stacked plan groups
+  equal-layout spans, and the gather serves them all at once.
+* ``resident`` picks the gather tier: a stack that fits the VMEM budget
+  is pinned whole; a larger one stays in HBM and each probed row is
+  DMA'd into VMEM.
+* Quarantined rows (filter block failed its checksum) have their filter
+  verdict forced to "maybe": fence-only pruning, never a false negative.
 
-Mixed capacity classes are the normal LSM case (level-0 runs share the
-smallest class, each lower level is one fanout bigger), so run rows have
-*different* layouts.  Rows are padded to one uniform ``rowpad`` lane
-width and the kernel body selects the right combine algebra per block
-through a **scalar-prefetched block-type table**: ``btype[rb]`` (SMEM)
-indexes a ``lax.switch`` over the distinct per-block layout tuples, each
-branch tracing that block's :class:`~repro.core.engine.StackedProbe`
-(one fused gather per tile per block).  Uniform stacks skip the switch.
-
-Fences ride along as per-run ``uint32`` key bounds; padding rows carry
-the empty fence ``(kmin, kmax) = (2^32-1, 0)`` so they can never be
-touched.  Verdicts are bit-identical to
-``StackedProbe.touch_all`` (the XLA-exact fallback) by construction:
-same plan, same gather lanes (shifted by the padded row bases), same
-combine, same fence compare — asserted per layout class in
-``tests/test_store_scan_kernel.py``.
+Verdicts are bit-identical to ``StackedProbe.touch_all`` (the XLA
+reference) by construction: same plan, same lanes, same words, same
+combine — asserted per layout class in ``tests/test_store_scan_kernel.py``.
 
 Layout restrictions: all rows share one key domain ``d <= 32`` and no
 exact segment (the store's capacity-class ladder satisfies both by
@@ -46,15 +32,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from ..core.engine import stacked_probe
+from .gather import TILE_ALIGN, gather_lanes, probe_tile
 from .rangeprobe import _check_range_kernel_layout
 
 __all__ = ["store_scan_probe", "build_run_stack", "DEFAULT_TILE"]
 
-DEFAULT_TILE = 256           # scan queries per grid step
+DEFAULT_TILE = 256           # scan queries per gather grid step
 
 
 def _round_up(x: int, m: int) -> int:
@@ -64,87 +49,33 @@ def _round_up(x: int, m: int) -> int:
 def build_run_stack(states) -> jax.Array:
     """Pad per-run filter states to one uniform ``(R, rowpad)`` stack.
 
-    Zero-padding is safe: padded lanes sit past every row's addressable
-    lane range, so no planned gather ever lands in them."""
-    rowpad = max(int(s.shape[0]) for s in states)
+    ``rowpad`` is a whole number of ``(8, 128)`` u32 tiles.  Zero-padding
+    is safe: padded lanes sit past every row's addressable lane range, so
+    no planned gather ever lands in them."""
+    rowpad = _round_up(max(int(s.shape[0]) for s in states), TILE_ALIGN)
     return jnp.stack([jnp.pad(s, (0, rowpad - s.shape[0])) for s in states])
-
-
-def _block_probes(layouts, rpb: int, rowpad: int):
-    """Per-run-block StackedProbe branches + the block-type table.
-
-    Blocks are consecutive ``rpb``-row slices of the run stack; a block
-    whose tail crosses ``R`` is padded by repeating its last layout (the
-    padding rows' empty fences keep their verdicts unreachable).  Returns
-    ``(probes, btype)`` where ``probes[btype[rb]]`` combines block
-    ``rb``'s rows at the padded row bases ``(0, rowpad, 2*rowpad, ...)``.
-    """
-    nblocks = _round_up(len(layouts), rpb) // rpb
-    bases = tuple(i * rowpad for i in range(rpb))
-    kinds, btype = {}, []
-    for b in range(nblocks):
-        lays = list(layouts[b * rpb:(b + 1) * rpb])
-        lays += [lays[-1]] * (rpb - len(lays))
-        key = tuple(lays)
-        if key not in kinds:
-            kinds[key] = len(kinds)
-        btype.append(kinds[key])
-    probes = [stacked_probe(key, bases) for key in kinds]
-    return probes, btype
-
-
-def _store_scan_kernel(btype_ref, quar_ref, lo_ref, hi_ref, kmin_ref,
-                       kmax_ref, stack_ref, fence_ref, touch_ref, *,
-                       probes, rpb):
-    lo = lo_ref[...]
-    hi = hi_ref[...]
-    kmin = kmin_ref[...]
-    kmax = kmax_ref[...]
-    # min/max fence masking fused with the probe: a run is touched only
-    # where the query interval overlaps its key range AND its filter says
-    # "maybe"
-    fence = (hi[:, None] >= kmin[None, :]) & (lo[:, None] <= kmax[None, :])
-    state = stack_ref[...].reshape(-1)
-    rb = pl.program_id(1)
-    if len(probes) == 1:
-        filt = probes[0]._range_all(state, lo, hi)
-    else:
-        # scalar-prefetched block-type table: pick this run block's
-        # combine algebra (distinct layout mixes trace distinct branches)
-        filt = jax.lax.switch(btype_ref[rb],
-                              [p._range_all for p in probes], state, lo, hi)
-    # scalar-prefetched quarantine mask (SMEM): rows whose filter block
-    # failed its checksum take the always-touch branch — the corrupted
-    # filter's verdict is discarded and the row degrades to fence-only
-    # pruning (a flipped bit must never skip a run: no false negatives)
-    quar = jnp.stack([quar_ref[rb * rpb + i] != 0 for i in range(rpb)])
-    fence_ref[...] = fence
-    touch_ref[...] = fence & (filt | quar[None, :])
 
 
 @functools.partial(jax.jit, static_argnums=(0, 6, 7, 8))
 def store_scan_probe(layouts, stack: jax.Array, kmin, kmax, lo, hi,
-                     tile: int = DEFAULT_TILE, runs_per_block: int = 0,
-                     interpret: bool = True, quarantine=None):
-    """Fused store-scan pruning: ``(fence, touch)`` in one kernel call.
+                     tile: int = DEFAULT_TILE, resident: bool = True,
+                     interpret=None, quarantine=None):
+    """Fused store-scan pruning: ``(fence, touch)`` with one kernel call.
 
     ``layouts`` is the static per-run layout tuple, ``stack`` the
     ``uint32[R, rowpad]`` padded filter stack (:func:`build_run_stack`),
     ``kmin``/``kmax`` the per-run key fences, ``lo``/``hi`` the scan
     bounds (clamped into the ``d``-bit domain by the caller).  Returns
     ``(fence, touch)``, both ``bool[B, R]`` — exactly what
-    ``StackedProbe.touch_all`` returns, from a single ``pallas_call``
+    ``StackedProbe.touch_all`` returns, with a single ``pallas_call``
     whatever the run mix (jaxpr-asserted in the test suite).
 
-    ``runs_per_block`` splits the run axis into VMEM-sized filter blocks
-    (0 = whole stack resident); the grid is ``(B/tile, R/runs_per_block)``
-    and the Pallas pipeline double-buffers each block's HBM DMA behind
-    the previous block's compute.
-
-    ``quarantine`` (optional ``(R,)`` bool/int mask) rides along as a
-    second scalar-prefetch operand: a True row's filter verdict is forced
-    to "maybe" inside the kernel, degrading it to fence-only pruning —
-    bit-identical to ``touch_all``'s quarantine handling.
+    ``resident`` pins the whole stack in VMEM; ``False`` leaves it in
+    HBM and DMAs each probed row (the caller sizes the stack against
+    the VMEM budget).  ``quarantine`` (optional ``(R,)``
+    bool/int mask) forces those rows' filter verdicts to "maybe".
+    ``interpret=None`` runs the kernel compiled on TPU and interpreted
+    elsewhere.
     """
     R = len(layouts)
     if R == 0:
@@ -158,55 +89,16 @@ def store_scan_probe(layouts, stack: jax.Array, kmin, kmax, lo, hi,
         if lay.total_u32 > rowpad:
             raise ValueError(f"stack rowpad {rowpad} < layout lanes "
                              f"{lay.total_u32}")
-    rpb = min(runs_per_block, R) if runs_per_block > 0 else R
-    nblocks = _round_up(R, rpb) // rpb
-    Rp = nblocks * rpb
-    probes, btype = _block_probes(layouts, rpb, rowpad)
-
+    probe = stacked_probe(tuple(layouts),
+                          tuple(r * rowpad for r in range(R)))
+    gather = functools.partial(
+        gather_lanes, resident=resident,
+        tile=probe_tile(tile, probe.range_gather_width, resident),
+        interpret=interpret)
     lo = jnp.atleast_1d(jnp.asarray(lo, jnp.uint32))
     hi = jnp.atleast_1d(jnp.asarray(hi, jnp.uint32))
-    B = lo.shape[0]
-    tile = min(tile, _round_up(max(B, 1), 8))
-    Bp = _round_up(max(B, 1), tile)
-    lo_p = jnp.pad(lo, (0, Bp - B))
-    hi_p = jnp.pad(hi, (0, Bp - B))
-    stack_p = jnp.pad(jnp.asarray(stack, jnp.uint32), ((0, Rp - R), (0, 0)))
-    # padding rows get the empty fence: kmin > kmax rejects every query
-    kmin_p = jnp.pad(jnp.asarray(kmin, jnp.uint32), (0, Rp - R),
-                     constant_values=jnp.uint32(0xFFFFFFFF))
-    kmax_p = jnp.pad(jnp.asarray(kmax, jnp.uint32), (0, Rp - R))
-    btype_arr = jnp.asarray(btype, jnp.int32)
-    # the quarantine mask is the second scalar-prefetch operand (SMEM);
-    # padding rows get 0 — their empty fence already rejects every query
-    if quarantine is None:
-        quar_arr = jnp.zeros((Rp,), jnp.int32)
-    else:
-        quar_arr = jnp.pad(
-            jnp.asarray(quarantine).astype(jnp.int32), (0, Rp - R))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(Bp // tile, nblocks),
-        in_specs=[
-            pl.BlockSpec((tile,), lambda t, rb, bt, q: (t,)),
-            pl.BlockSpec((tile,), lambda t, rb, bt, q: (t,)),
-            pl.BlockSpec((rpb,), lambda t, rb, bt, q: (rb,)),
-            pl.BlockSpec((rpb,), lambda t, rb, bt, q: (rb,)),
-            pl.BlockSpec((rpb, rowpad), lambda t, rb, bt, q: (rb, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile, rpb), lambda t, rb, bt, q: (t, rb)),
-            pl.BlockSpec((tile, rpb), lambda t, rb, bt, q: (t, rb)),
-        ],
-    )
-    # named_scope: device-trace annotation only — no jaxpr equations, so
-    # the one-pallas_call invariant is asserted with the scope in place
-    with jax.named_scope("bloomrf/store_scan/pallas_call"):
-        fence, touch = pl.pallas_call(
-            functools.partial(_store_scan_kernel, probes=probes, rpb=rpb),
-            grid_spec=grid_spec,
-            out_shape=[jax.ShapeDtypeStruct((Bp, Rp), jnp.bool_),
-                       jax.ShapeDtypeStruct((Bp, Rp), jnp.bool_)],
-            interpret=interpret,
-        )(btype_arr, quar_arr, lo_p, hi_p, kmin_p, kmax_p, stack_p)
-    return fence[:B, :R], touch[:B, :R]
+    with jax.named_scope("bloomrf/store_scan"):
+        return probe._touch_all(
+            jnp.asarray(stack, jnp.uint32).reshape(-1),
+            jnp.asarray(kmin, jnp.uint32), jnp.asarray(kmax, jnp.uint32),
+            lo, hi, quarantine, gather=gather)

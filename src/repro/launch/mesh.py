@@ -16,15 +16,22 @@ import jax
 __all__ = ["make_production_mesh", "make_smoke_mesh"]
 
 
+def _mesh(shape, axes):
+    # Auto axes: the models place activations with sharding constraints,
+    # which jax.make_mesh's default Explicit axes refuse
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_smoke_mesh():
     """A tiny mesh over however many devices the test process has."""
     n = len(jax.devices())
     if n >= 4:
-        return jax.make_mesh((2, n // 2), ("data", "model"))
-    return jax.make_mesh((1, n), ("data", "model"))
+        return _mesh((2, n // 2), ("data", "model"))
+    return _mesh((1, n), ("data", "model"))

@@ -28,9 +28,9 @@ control flow): :func:`truncate_tail` tears the WAL's final bytes,
 failure replays exactly (``BLOOMRF_FAULT_SEED``).
 
 ``fail_pallas`` arms the kernel-dispatch seam (``kernel.dispatch``) with
-a countdown of its own: the store-scan megakernel raises at dispatch and
-``scan_backend="auto"`` must fall back to the XLA probe plane
-(``StoreStats.kernel_fallbacks``) instead of failing the scan.
+a countdown of its own: the store-scan kernel raises at dispatch, and the
+error must reach the caller whatever ``scan_backend`` says — there is no
+silent retry through the XLA probe plane.
 """
 from __future__ import annotations
 
@@ -74,9 +74,8 @@ class FaultPlan:
     ``crashes`` maps seam name -> hit countdown: ``{"wal.append": 3}``
     crashes on the third append.  ``fail_pallas`` is sugar for the
     ``kernel.dispatch`` seam, except it raises a plain ``RuntimeError``
-    (a kernel dispatch failure is an *error the store must absorb*, not a
-    process death — the auto backend falls back to XLA and keeps
-    serving)."""
+    (a kernel dispatch failure is an error the scan reports, not a
+    process death)."""
 
     seed: int = 0xFA17
     crashes: Dict[str, int] = dataclasses.field(default_factory=dict)

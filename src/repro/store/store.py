@@ -114,7 +114,9 @@ class StoreConfig:
                                     # StackedProbe.touch_all reference,
                                     # "auto" picks the kernel on TPU only
                                     # (interpret-mode Pallas is slow on CPU)
-    use_insert_kernels: bool = False  # route rebuilds through FilterOps.insert
+    use_insert_kernels: Optional[bool] = None  # filter builds through the
+                                    # Pallas insert kernel (FilterOps.insert);
+                                    # None = on TPU only, as scans
     value_bytes: int = 64           # per-entry data-block size for accounting
     seed: int = 0x0B100F11
     mutability: str = "insert_only"  # "insert_only" | "deletable"
@@ -233,18 +235,14 @@ class StoreStats:
     wal_replayed: int = 0           # records recovered at the last open
     degraded_probes: int = 0        # (query, run) cells answered fence-only
                                     # because the run is quarantined
-    kernel_fallbacks: int = 0       # scan batches retried through the XLA
-                                    # plane after a pallas_call dispatch error
 
     # Counters that survive Store.snapshot()/restore(): the write-path
-    # history that produced the snapshotted runs, plus kernel_fallbacks
-    # (a degradation odometer that must not silently reset with the
-    # process).  Read-path counters, wal_appends/wal_replayed and
-    # degraded_probes describe THIS process's traffic and stay local.
+    # history that produced the snapshotted runs.  Read-path counters,
+    # wal_appends/wal_replayed and degraded_probes describe THIS
+    # process's traffic and stay local.
     DURABLE: ClassVar[Tuple[str, ...]] = (
         "puts", "deletes", "flushes", "compactions", "or_merges",
-        "rebuild_merges", "promote_merges", "purge_rebuilds", "retunes",
-        "kernel_fallbacks")
+        "rebuild_merges", "promote_merges", "purge_rebuilds", "retunes")
 
     @property
     def runs_probed_per_scan(self) -> float:
@@ -363,9 +361,20 @@ class Store:
                             seed=self.cfg.seed)
 
     def _build_filter(self, layout, keys: np.ndarray) -> jnp.ndarray:
-        """Bulk filter build; the compaction rebuild path lands here too."""
+        """Bulk filter build; the compaction rebuild path lands here too.
+
+        Keys are padded to a power-of-two count by repeating one of them
+        (inserting a key twice sets no new bit), so each layout compiles
+        for a few shapes, not for every run length."""
+        n = len(keys)
+        if n:
+            keys = np.concatenate(
+                [keys, np.repeat(keys[:1], (1 << (n - 1).bit_length()) - n)])
         kj = jnp.asarray(keys, self.kdtype)
-        if self.cfg.use_insert_kernels and layout.d <= 32:
+        kernels = self.cfg.use_insert_kernels
+        if kernels is None:
+            kernels = jax.default_backend() == "tpu"
+        if kernels and layout.d <= 32:
             if layout not in self._ops:
                 self._ops[layout] = FilterOps(layout, _warn=False)
             ops = self._ops[layout]
@@ -670,19 +679,17 @@ class Store:
         return "kernel" if jax.default_backend() == "tpu" else "xla"
 
     def _kernel_inputs(self):
-        """Megakernel operands for the live stack, built once per refresh:
-        the padded ``(R, rowpad)`` run stack, uint32 device fences, and a
-        ``runs_per_block`` split sized so one filter block fits the VMEM
-        budget (the Pallas grid pipeline streams blocks beyond it)."""
+        """Scan-kernel operands for the live stack, built once per
+        refresh: the padded ``(R, rowpad)`` run stack, uint32 device
+        fences, and the gather tier: ``resident`` while the whole stack
+        fits the VMEM budget, else the stack stays in HBM."""
         if self._kstate is None:
             layouts = tuple(r.layout for r in self._runs)
             stack = build_run_stack([r.state for r in self._runs])
-            rowpad, R = int(stack.shape[1]), len(self._runs)
-            budget = read_vmem_budget_u32()
-            rpb = R if rowpad * R <= budget else max(1, budget // rowpad)
+            resident = stack.size <= read_vmem_budget_u32()
             self._kstate = (layouts, stack,
                             jnp.asarray(self._kmins, jnp.uint32),
-                            jnp.asarray(self._kmaxs, jnp.uint32), int(rpb))
+                            jnp.asarray(self._kmaxs, jnp.uint32), resident)
         return self._kstate
 
     def _touch_masks(self, lo: np.ndarray,
@@ -697,36 +704,29 @@ class Store:
             z = np.zeros((len(lo), 0), bool)
             return z, z
         if self._scan_kernel_mode() == "kernel":
-            try:
-                self._fault("kernel.dispatch")
-                dmax = np.uint64((1 << self.cfg.d) - 1)
-                layouts, stack, kmin_d, kmax_d, rpb = self._kernel_inputs()
-                f, t = store_scan_probe(
-                    layouts, stack, kmin_d, kmax_d,
-                    jnp.asarray(np.minimum(lo, dmax), jnp.uint32),
-                    jnp.asarray(np.minimum(hi, dmax), jnp.uint32),
-                    STORE_SCAN_TILE, rpb, jax.default_backend() != "tpu",
-                    self._quar_device())
-                fence, touch = np.asarray(f), np.asarray(t)
-            except Exception:
-                # a dispatch-time pallas_call failure is survivable when
-                # the caller asked for "auto": retry the batch through the
-                # XLA probe plane (bit-identical verdicts) exactly once
-                if self.cfg.scan_backend != "auto":
-                    raise
-                self.stats.kernel_fallbacks += 1
-            else:
-                # the uint32 clamp is exact for every in-domain `lo` (kmin,
-                # kmax <= dmax); intervals entirely above the domain must be
-                # fenced off on the host instead (kmax <= dmax < lo)
-                dead = lo > dmax
-                if dead.any():
-                    fence, touch = fence.copy(), touch.copy()
-                    fence[dead] = touch[dead] = False
-                if self._quar.any():
-                    self.stats.degraded_probes += int(
-                        (fence & self._quar[None, :]).sum())
-                return fence, touch
+            # a kernel failure fails the scan: there is no silent retry
+            # through the XLA plane, so a TPU scan that returns did run
+            # the kernel
+            self._fault("kernel.dispatch")
+            dmax = np.uint64((1 << self.cfg.d) - 1)
+            layouts, stack, kmin_d, kmax_d, resident = self._kernel_inputs()
+            f, t = store_scan_probe(
+                layouts, stack, kmin_d, kmax_d,
+                jnp.asarray(np.minimum(lo, dmax), jnp.uint32),
+                jnp.asarray(np.minimum(hi, dmax), jnp.uint32),
+                STORE_SCAN_TILE, resident, None, self._quar_device())
+            fence, touch = np.asarray(f), np.asarray(t)
+            # the uint32 clamp is exact for every in-domain `lo` (kmin,
+            # kmax <= dmax); intervals entirely above the domain must be
+            # fenced off on the host instead (kmax <= dmax < lo)
+            dead = lo > dmax
+            if dead.any():
+                fence, touch = fence.copy(), touch.copy()
+                fence[dead] = touch[dead] = False
+            if self._quar.any():
+                self.stats.degraded_probes += int(
+                    (fence & self._quar[None, :]).sum())
+            return fence, touch
         fence, filt = self.probe_runs(lo, hi, point=False)
         return fence, fence & filt
 
@@ -750,17 +750,11 @@ class Store:
             z = jnp.zeros((lo.shape[0], 0), bool)
             return z, z
         if self._scan_kernel_mode() == "kernel":
-            try:
-                self._fault("kernel.dispatch")
-                layouts, stack, kmin_d, kmax_d, rpb = self._kernel_inputs()
-                return store_scan_probe(layouts, stack, kmin_d, kmax_d,
-                                        lo, hi, STORE_SCAN_TILE, rpb,
-                                        jax.default_backend() != "tpu",
-                                        self._quar_device())
-            except Exception:
-                if self.cfg.scan_backend != "auto":
-                    raise
-                self.stats.kernel_fallbacks += 1
+            self._fault("kernel.dispatch")
+            layouts, stack, kmin_d, kmax_d, resident = self._kernel_inputs()
+            return store_scan_probe(layouts, stack, kmin_d, kmax_d,
+                                    lo, hi, STORE_SCAN_TILE, resident, None,
+                                    self._quar_device())
         if self._fence_dev is None:
             self._fence_dev = (jnp.asarray(self._kmins, self.kdtype),
                                jnp.asarray(self._kmaxs, self.kdtype))
@@ -965,6 +959,10 @@ class Store:
                     r.alt.build(r.keys)
         stats_enc = snap.get("stats")    # optional: absent in v1/v2 or
         if stats_enc is not None:        # pre-§15 v3 snapshots
+            # kernel_fallbacks: a retired counter older snapshots carry
+            stats_enc = ({k: v for k, v in stats_enc.items()
+                          if k != "kernel_fallbacks"}
+                         if isinstance(stats_enc, dict) else stats_enc)
             if (not isinstance(stats_enc, dict)
                     or not set(stats_enc) <= set(StoreStats.DURABLE)
                     or not all(isinstance(v, int) and not isinstance(v, bool)

@@ -195,8 +195,8 @@ def test_multisegment_replicas_single_gather_jaxpr():
 # partitioned range kernel parity (resident vs partitioned vs XLA)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("block_u32", [256, 2048])
-def test_range_probe_partitioned_parity(rng, block_u32):
+@pytest.mark.parametrize("tile", [32, 512])
+def test_range_probe_partitioned_parity(rng, tile):
     from repro.kernels import (FilterOps, range_probe_partitioned,
                                range_probe_resident)
     from repro.kernels import ref as kref
@@ -210,7 +210,7 @@ def test_range_probe_partitioned_parity(rng, block_u32):
     want = np.asarray(kref.range_ref(lay, state, jnp.asarray(lo),
                                      jnp.asarray(hi)))
     part = np.asarray(range_probe_partitioned(
-        lay, state, jnp.asarray(lo), jnp.asarray(hi), 128, block_u32, True))
+        lay, state, jnp.asarray(lo), jnp.asarray(hi), tile, True))
     np.testing.assert_array_equal(want, part)
     res = np.asarray(range_probe_resident(
         lay, state, jnp.asarray(lo), jnp.asarray(hi), 256, True))
@@ -224,8 +224,7 @@ def test_range_probe_partitioned_parity(rng, block_u32):
     slo = np.maximum(keys.astype(np.int64) - 2, 0).astype(np.uint32)
     shi = np.minimum(keys.astype(np.int64) + 2, (1 << 32) - 1).astype(np.uint32)
     assert np.asarray(range_probe_partitioned(
-        lay, state, jnp.asarray(slo), jnp.asarray(shi), 128, block_u32,
-        True)).all()
+        lay, state, jnp.asarray(slo), jnp.asarray(shi), tile, True)).all()
 
 
 def test_range_probe_partitioned_rejects_exact():
@@ -238,7 +237,7 @@ def test_range_probe_partitioned_rejects_exact():
     state = f.build(jnp.asarray(np.arange(300, dtype=np.uint32)))
     lo = jnp.asarray(np.arange(10, dtype=np.uint32))
     with pytest.raises(ValueError, match="exact-layer"):
-        range_probe_partitioned(lay, state, lo, lo, 128, 256, True)
+        range_probe_partitioned(lay, state, lo, lo, 128, True)
 
 
 # ---------------------------------------------------------------------------

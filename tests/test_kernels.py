@@ -45,15 +45,15 @@ def test_point_probe_resident(rng, d, delta, tile):
     assert got[:500].all()  # no false negatives through the kernel
 
 
-@pytest.mark.parametrize("block_u32", [256, 2048])
-def test_point_probe_partitioned(rng, block_u32):
+@pytest.mark.parametrize("tile", [32, 512])
+def test_point_probe_partitioned(rng, tile):
     lay = basic_layout(32, 5000, 14.0, delta=6)
     keys = _keys(rng, 32, 5000)
     state = BloomRF(lay).build(jnp.asarray(keys, jnp.uint32))
     qs = np.concatenate([keys[:300], _keys(rng, 32, 700)])
     want = np.asarray(kref.point_ref(lay, state, jnp.asarray(qs)))
     got = np.asarray(point_probe_partitioned(lay, state, jnp.asarray(qs),
-                                             128, block_u32, True))
+                                             tile, True))
     assert (want == got).all()
 
 

@@ -262,12 +262,12 @@ def test_store_scan_one_pallas_call_with_obs_enabled(rng):
         st.put(int(k), 0)
     st.flush()
     st._refresh()
-    layouts, stack, kmin_d, kmax_d, rpb = st._kernel_inputs()
+    layouts, stack, kmin_d, kmax_d, resident = st._kernel_inputs()
     lo = jnp.zeros(64, jnp.uint32)
     hi = jnp.full(64, 1 << 20, jnp.uint32)
     jaxpr = jax.make_jaxpr(
         lambda s, a, b: store_scan_probe(layouts, s, kmin_d, kmax_d,
-                                         a, b, 256, rpb, True))(stack, lo, hi)
+                                         a, b, 256, resident, True))(stack, lo, hi)
     assert _count_prim(jaxpr.jaxpr, "pallas_call") == 1
     # the dispatch odometer ticks on the host, outside the traced fn
     st.scan_probe_device(lo, hi)
@@ -281,13 +281,13 @@ def test_store_scan_one_pallas_call_with_obs_enabled(rng):
 
 def test_store_stats_snapshot_and_reset():
     s = StoreStats()
-    s.puts, s.kernel_fallbacks = 7, 2
+    s.puts, s.retunes = 7, 2
     assert s.snapshot()["puts"] == 7
     assert s.durable_snapshot() == {
         **{name: 0 for name in StoreStats.DURABLE},
-        "puts": 7, "kernel_fallbacks": 2}
+        "puts": 7, "retunes": 2}
     s.reset()
-    assert s.puts == 0 and s.kernel_fallbacks == 0
+    assert s.puts == 0 and s.retunes == 0
 
 
 def test_store_register_obs_family(rng):
@@ -306,7 +306,7 @@ def test_durable_stats_survive_snapshot_restore(rng):
     for k in rng.integers(0, 1 << 20, 400, dtype=np.uint64):
         src.put(int(k), 1)
     src.delete(int(rng.integers(1 << 20)))
-    src.stats.kernel_fallbacks = 3        # process-observed, durable
+    src.stats.retunes = 3                 # write-path history, durable
     src.stats.gets = 99                   # read-path: process-local only
     snap = src.snapshot()
     dst = Store.restore(snap)

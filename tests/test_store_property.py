@@ -23,9 +23,21 @@ except ImportError:                                     # pragma: no cover
     hst = None
 
 
+def _pow2(keys):
+    """Pad a key batch to a power-of-two length by repeating its first key.
+
+    A repeated key sets no new bit and probes exactly as it did, so every
+    check is unchanged; the batch shapes JAX compiles for stay few."""
+    keys = np.asarray(keys)
+    n = len(keys)
+    return np.concatenate([keys, np.repeat(keys[:1],
+                                           (1 << (n - 1).bit_length()) - n)])
+
+
 def _check_union_no_fn(layout, state, union_keys):
     """Every union key (and every straddling range) probes positive."""
     f = BloomRF(layout)
+    union_keys = _pow2(union_keys)
     kj = jnp.asarray(union_keys, f.kdtype)
     assert np.asarray(f.point(state, kj)).all()
     ks = np.asarray(union_keys, np.uint64)
@@ -37,18 +49,17 @@ def _check_union_no_fn(layout, state, union_keys):
 
 def _merge_case(layout_a, layout_b, target, keys_a, keys_b):
     """Merge two runs' filters under ``target``; verify vs bulk rebuild."""
-    fa, fb = BloomRF(layout_a), BloomRF(layout_b)
-    run_a = Run(np.unique(keys_a), [0] * len(np.unique(keys_a)),
-                np.zeros(len(np.unique(keys_a)), bool), 0, layout_a,
-                fa.build(jnp.asarray(np.unique(keys_a), fa.kdtype)))
-    run_b = Run(np.unique(keys_b), [0] * len(np.unique(keys_b)),
-                np.zeros(len(np.unique(keys_b)), bool), 1, layout_b,
-                fb.build(jnp.asarray(np.unique(keys_b), fb.kdtype)))
-    union = np.unique(np.concatenate([keys_a, keys_b]))
-
     def build(lay, keys):
         f = BloomRF(lay)
-        return f.build(jnp.asarray(keys, f.kdtype))
+        return f.build(jnp.asarray(_pow2(keys), f.kdtype))
+
+    run_a = Run(np.unique(keys_a), [0] * len(np.unique(keys_a)),
+                np.zeros(len(np.unique(keys_a)), bool), 0, layout_a,
+                build(layout_a, np.unique(keys_a)))
+    run_b = Run(np.unique(keys_b), [0] * len(np.unique(keys_b)),
+                np.zeros(len(np.unique(keys_b)), bool), 1, layout_b,
+                build(layout_b, np.unique(keys_b)))
+    union = np.unique(np.concatenate([keys_a, keys_b]))
 
     state, how = merge_filter_state([run_a, run_b], target, union, build)
     via_or = how == "or"

@@ -130,31 +130,31 @@ def _reference(layouts, states, kmin, kmax, lo, hi):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("cls", sorted(CLASSES))
-@pytest.mark.parametrize("rpb", [0, 2])
-def test_kernel_matches_stacked_probe(rng, cls, rpb):
+@pytest.mark.parametrize("resident", [True, False])
+def test_kernel_matches_stacked_probe(rng, cls, resident):
     layouts, states, kmin, kmax = CLASSES[cls](rng)
     lo, hi = _queries(rng)
     f_ref, t_ref = _reference(layouts, states, kmin, kmax, lo, hi)
     stack = build_run_stack(states)
     f_k, t_k = store_scan_probe(layouts, stack,
                                 jnp.asarray(kmin), jnp.asarray(kmax),
-                                lo, hi, 64, rpb, True)
+                                lo, hi, 64, resident, True)
     assert np.array_equal(np.asarray(f_k), np.asarray(f_ref)), cls
     assert np.array_equal(np.asarray(t_k), np.asarray(t_ref)), cls
 
 
 def test_kernel_odd_batch_and_tiny_tile(rng):
-    """B not a multiple of the tile; rpb that doesn't divide R."""
+    """B not a multiple of the tile; a tile smaller than one row's plan."""
     layouts, states, kmin, kmax = _capacity_ladder(rng)   # R = 4
     lo, hi = _queries(rng, b=77)
     f_ref, t_ref = _reference(layouts, states, kmin, kmax, lo, hi)
     stack = build_run_stack(states)
-    for rpb in (1, 3):                 # 4 and 2 blocks, tail-padded
+    for resident in (True, False):
         f_k, t_k = store_scan_probe(layouts, stack,
                                     jnp.asarray(kmin), jnp.asarray(kmax),
-                                    lo, hi, 32, rpb, True)
-        assert np.array_equal(np.asarray(f_k), np.asarray(f_ref)), rpb
-        assert np.array_equal(np.asarray(t_k), np.asarray(t_ref)), rpb
+                                    lo, hi, 1, resident, True)
+        assert np.array_equal(np.asarray(f_k), np.asarray(f_ref)), resident
+        assert np.array_equal(np.asarray(t_k), np.asarray(t_ref)), resident
 
 
 def test_kernel_rejects_bad_stacks(rng):
@@ -193,13 +193,13 @@ def test_fused_scan_is_one_pallas_call(rng):
     layouts, states, kmin, kmax = _mixed_delta(rng)
     stack = build_run_stack(states)
     lo, hi = _queries(rng, b=64)
-    for rpb in (0, 1):                 # whole-stack AND multi-block grids
+    for resident in (True, False):     # VMEM-resident AND hbm tiers
         jaxpr = jax.make_jaxpr(
             lambda s, a, b: store_scan_probe(
                 layouts, s, jnp.asarray(kmin), jnp.asarray(kmax),
-                a, b, 64, rpb, True))(stack, lo, hi)
+                a, b, 64, resident, True))(stack, lo, hi)
         assert _count_prim(jaxpr.jaxpr, "pallas_call") == 1, (
-            rpb, jaxpr.pretty_print())
+            resident, jaxpr.pretty_print())
 
 
 def test_store_kernel_path_is_one_pallas_call(rng):
@@ -210,12 +210,12 @@ def test_store_kernel_path_is_one_pallas_call(rng):
         st.put(int(k), 0)
     st.flush()
     st._refresh()
-    layouts, stack, kmin_d, kmax_d, rpb = st._kernel_inputs()
+    layouts, stack, kmin_d, kmax_d, resident = st._kernel_inputs()
     lo = jnp.zeros(64, jnp.uint32)
     hi = jnp.full(64, 1 << 20, jnp.uint32)
     jaxpr = jax.make_jaxpr(
         lambda s, a, b: store_scan_probe(layouts, s, kmin_d, kmax_d,
-                                         a, b, 256, rpb, True))(stack, lo, hi)
+                                         a, b, 256, resident, True))(stack, lo, hi)
     assert _count_prim(jaxpr.jaxpr, "pallas_call") == 1
 
 
