@@ -1,0 +1,6 @@
+"""filter_qps: filter queries answered per second of the window."""
+from bench.stats import ops_per_s
+
+
+def read(run):
+    return ops_per_s(run)
