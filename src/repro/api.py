@@ -381,14 +381,24 @@ def chunked_probe(fn, state, arrays, kdtype, chunk: int) -> np.ndarray:
 
     The single copy of the chunking loop used by every façade handle and
     by :class:`~repro.filters.BloomRFAdapter` — one compiled shape serves
-    arbitrarily large query batches."""
+    arbitrarily large query batches.  Each chunk is four spans: the
+    bounds' transfer in, the dispatch, the wait for the device, and the
+    copy back (the wait is its own step so the copy times the copy)."""
+    import jax
     import jax.numpy as jnp
 
+    span = _obs_trace.span
     outs = []
     B = len(arrays[0])
     for s in range(0, B, chunk):
-        args = [jnp.asarray(a[s:s + chunk], kdtype) for a in arrays]
-        outs.append(np.asarray(fn(state, *args)))
+        with span("probe/put"):
+            args = [jnp.asarray(a[s:s + chunk], kdtype) for a in arrays]
+        with span("probe/dispatch"):
+            out = fn(state, *args)
+        with span("probe/wait"):
+            jax.block_until_ready(out)
+        with span("probe/fetch"):
+            outs.append(np.asarray(out))
     return np.concatenate(outs) if outs else np.zeros(0, bool)
 
 
@@ -546,7 +556,7 @@ class SingleFilter(_Handle):
         import jax.numpy as jnp
 
         kd = self.filter.kdtype
-        with _obs_trace.span("facade/insert", n=len(codes)):
+        with _obs_trace.span("facade/insert"):
             for s in range(0, len(codes), self.spec.chunk):
                 cj = jnp.asarray(codes[s:s + self.spec.chunk], kd)
                 if self.gens is not None:
@@ -607,15 +617,17 @@ class SingleFilter(_Handle):
 
     # -- probes -----------------------------------------------------------
     def point(self, qs) -> np.ndarray:
-        codes = self.codec.encode_point(qs)
-        with _obs_trace.span("facade/point", n=len(codes)):
+        with _obs_trace.span("facade/point"):
+            with _obs_trace.span("facade/encode"):
+                codes = self.codec.encode_point(qs)
             return chunked_probe(self._point, self.state, [codes],
                                  self.filter.kdtype, self.spec.chunk)
 
     def range(self, lo, hi) -> np.ndarray:
-        clo, chi = self.codec.encode_bounds(lo, hi)
-        self._observe_ranges(clo, chi)
-        with _obs_trace.span("facade/range", n=len(clo)):
+        with _obs_trace.span("facade/range"):
+            with _obs_trace.span("facade/encode"):
+                clo, chi = self.codec.encode_bounds(lo, hi)
+            self._observe_ranges(clo, chi)
             return chunked_probe(self._range, self.state, [clo, chi],
                                  self.filter.kdtype, self.spec.chunk)
 
@@ -665,22 +677,24 @@ class BankFilter(_Handle):
             self._fpr_sampler().observe_insert(codes)
         import jax.numpy as jnp
 
-        with _obs_trace.span("facade/insert", n=len(codes)):
+        with _obs_trace.span("facade/insert"):
             for s in range(0, len(codes), self.spec.chunk):
                 self.state = self.bank.insert(
                     self.state, jnp.asarray(codes[s:s + self.spec.chunk],
                                             self.bank.kdtype))
 
     def point(self, qs) -> np.ndarray:
-        codes = self.codec.encode_point(qs)
-        with _obs_trace.span("facade/point", n=len(codes)):
+        with _obs_trace.span("facade/point"):
+            with _obs_trace.span("facade/encode"):
+                codes = self.codec.encode_point(qs)
             return chunked_probe(self.bank.point, self.state, [codes],
                                  self.bank.kdtype, self.spec.chunk)
 
     def range(self, lo, hi) -> np.ndarray:
-        clo, chi = self.codec.encode_bounds(lo, hi)
-        self._observe_ranges(clo, chi)
-        with _obs_trace.span("facade/range", n=len(clo)):
+        with _obs_trace.span("facade/range"):
+            with _obs_trace.span("facade/encode"):
+                clo, chi = self.codec.encode_bounds(lo, hi)
+            self._observe_ranges(clo, chi)
             return chunked_probe(self.bank.range, self.state, [clo, chi],
                                  self.bank.kdtype, self.spec.chunk)
 
@@ -855,10 +869,11 @@ class TenantFilter(_Handle):
     def point(self, tenants, qs) -> np.ndarray:
         import jax.numpy as jnp
 
-        codes = self.codec.encode_point(qs)
-        t = self._tiled_tenants(tenants, len(codes))
         out = []
-        with _obs_trace.span("facade/point", n=len(codes)):
+        with _obs_trace.span("facade/point"):
+            with _obs_trace.span("facade/encode"):
+                codes = self.codec.encode_point(qs)
+            t = self._tiled_tenants(tenants, len(codes))
             for s in range(0, len(codes), self.spec.chunk):
                 out.append(np.asarray(self.bank.point(
                     self.state, jnp.asarray(t[s:s + self.spec.chunk]),
@@ -869,15 +884,16 @@ class TenantFilter(_Handle):
     def range(self, tenants, lo, hi, use_meta: bool = True) -> np.ndarray:
         import jax.numpy as jnp
 
-        clo, chi = self.codec.encode_bounds(lo, hi)
-        t = self._tiled_tenants(tenants, len(clo))
-        self._observe_ranges(clo, chi)
-        if self._wl_sampler is not None:
-            # adaptive tuning samples regardless of the obs-plane flag
-            self._wl_sampler.observe_ranges(clo, chi)
         record_skips = _obs_metrics.enabled() and use_meta
         out = []
-        with _obs_trace.span("facade/range", n=len(clo)):
+        with _obs_trace.span("facade/range"):
+            with _obs_trace.span("facade/encode"):
+                clo, chi = self.codec.encode_bounds(lo, hi)
+            t = self._tiled_tenants(tenants, len(clo))
+            self._observe_ranges(clo, chi)
+            if self._wl_sampler is not None:
+                # adaptive tuning samples regardless of the obs-plane flag
+                self._wl_sampler.observe_ranges(clo, chi)
             for s in range(0, len(clo), self.spec.chunk):
                 tj = jnp.asarray(t[s:s + self.spec.chunk])
                 lj = jnp.asarray(clo[s:s + self.spec.chunk],
@@ -1041,7 +1057,7 @@ class TypedStore(_Handle):
         if self._buckets:
             return [self.get(k) for k in keys]
         codes = self.codec.encode_point(keys)
-        with _obs_trace.span("facade/get", batch=len(codes)):
+        with _obs_trace.span("facade/get"):
             return self.store.get_many(codes)
 
     def scan(self, lo, hi) -> list:
@@ -1049,22 +1065,25 @@ class TypedStore(_Handle):
 
     def scan_many(self, los, his) -> list:
         """Batched typed scans: one fused filter gather for the batch."""
-        if self._buckets:
-            clo, chi = self.codec.encode_bounds(los, his)
+        span = _obs_trace.span
+        with span("facade/scan"):
+            with span("facade/encode"):
+                clo, chi = (self.codec.encode_bounds(los, his) if self._buckets
+                            else self.codec.encode_bounds(np.asarray(los),
+                                                          np.asarray(his)))
             self._observe_ranges(clo, chi)
-            with _obs_trace.span("facade/scan", batch=len(clo)):
-                raw = self.store.scan_many(clo, chi)
-            # typed bounds ride along: buckets post-filter by string order
-            return [self._decode_scan(rows, lo, hi)
-                    for rows, lo, hi in zip(raw, los, his)]
-        clo, chi = self.codec.encode_bounds(np.asarray(los), np.asarray(his))
-        self._observe_ranges(clo, chi)
-        # iterate the encoded per-query bounds, NOT the caller's container —
-        # multiattr column-form bounds are a (2, B) array whose first axis
-        # is (a, b), so zipping the raw input would truncate the batch to 2
-        with _obs_trace.span("facade/scan", batch=len(clo)):
             raw = self.store.scan_many(clo, chi)
-        return [self._decode_scan(rows, None, None) for rows in raw]
+            with span("facade/decode"):
+                if self._buckets:
+                    # typed bounds ride along: buckets post-filter by
+                    # string order
+                    return [self._decode_scan(rows, lo, hi)
+                            for rows, lo, hi in zip(raw, los, his)]
+                # iterate the store's per-query answers, NOT the caller's
+                # container — multiattr column-form bounds are a (2, B)
+                # array whose first axis is (a, b), so zipping the raw
+                # input would truncate the batch to 2
+                return [self._decode_scan(rows, None, None) for rows in raw]
 
     def _decode_scan(self, rows: list, lo, hi) -> list:
         if self._buckets:

@@ -8,8 +8,9 @@ Three rules keep the registry safe to wire through hot paths:
   the read point, so jitted / timed loops never pay a host sync for
   telemetry.
 * **Disabled by default.**  The global flag (``BLOOMRF_OBS`` env var, or
-  :func:`enable`/:func:`disable`) gates every instrumentation *site*;
-  with it off the production paths do one boolean check and move on.
+  :func:`enable`/:func:`disable`) gates every metrics *site* (spans have
+  a switch of their own, ``obs/trace.py``); with it off the production
+  paths do one boolean check and move on.
   The registry itself always works — the flag guards the call sites,
   not the data structures.
 * **Families, not forks.**  Pre-existing ad-hoc counters (``StoreStats``,

@@ -1,17 +1,21 @@
 """Span tracing: host-side timing contexts around production paths (§15).
 
-``span(name)`` returns a context manager.  With observability disabled
-it is a shared do-nothing singleton — one boolean check, zero
-allocation.  Enabled, a span:
+``span(name)`` returns a context manager.  Spans have their own switch
+(:func:`enable` / :func:`disable` here, or ``BLOOMRF_OBS=1`` and
+``repro.obs.enable()``, which turn on the metrics plane too).  Off, a
+span is a shared do-nothing singleton: one call, zero allocation.  On,
+a span:
 
-* wraps the body in ``jax.profiler.TraceAnnotation`` so the op shows up
-  in a device trace when a profiler session is active (and costs ~nothing
-  when one is not),
+* wraps the body in ``jax.profiler.TraceAnnotation("bloomrf/<name>")``,
+  so the op shows up on the device trace's clock when a profiler
+  session is active (and costs ~nothing when one is not), and
 * feeds the wall-clock duration into the per-op-class latency histogram
-  ``obs/latency/{name}`` in the global registry (p50/p99 come from
-  there), and
-* appends a structured ``bloomrf-trace/v1`` JSONL record when a trace
-  sink has been set via :func:`set_trace_sink`.
+  ``obs/latency/{name}`` in the global registry when the metrics plane
+  is on as well (p50/p99 come from there).
+
+Spans alone turn on nothing else: no FPR sampler, no family
+registration, no histogram, so a profiled run pays only for the
+annotations.
 
 Spans are HOST-side only.  Inside jitted functions the engine and the
 store-scan kernel use ``jax.named_scope`` instead — a trace-time
@@ -21,31 +25,30 @@ or off (pinned by ``tests/test_obs.py``).
 """
 from __future__ import annotations
 
-import json
 import time
 
 from . import metrics as _metrics
 
-TRACE_SCHEMA = "bloomrf-trace/v1"
+PREFIX = "bloomrf/"
 
-_sink_path: str | None = None
-_sink_file = None
+_ENABLED = _metrics.enabled()   # BLOOMRF_OBS=1 starts both switches on
 _TraceAnnotation = None     # resolved on first enabled span (lazy jax)
 
 
-def set_trace_sink(path: str | None) -> None:
-    """Append JSONL span records to ``path`` (``None`` closes the sink)."""
-    global _sink_path, _sink_file
-    if _sink_file is not None:
-        _sink_file.close()
-    _sink_path, _sink_file = None, None
-    if path:
-        _sink_path = str(path)
-        _sink_file = open(path, "a", encoding="utf-8")
+def enabled() -> bool:
+    """Are spans on?"""
+    return _ENABLED
 
 
-def trace_sink() -> str | None:
-    return _sink_path
+def enable() -> None:
+    """Turn spans on (alone: the metrics plane keeps its own switch)."""
+    global _ENABLED
+    _ENABLED = True
+
+
+def disable() -> None:
+    global _ENABLED
+    _ENABLED = False
 
 
 class _NullSpan:
@@ -64,20 +67,16 @@ NULL_SPAN = _NullSpan()
 
 
 class Span:
-    __slots__ = ("name", "attrs", "_t0", "_prof")
+    """An annotated span that also feeds ``obs/latency/{name}``."""
 
-    def __init__(self, name: str, attrs: dict):
+    __slots__ = ("name", "_t0", "_prof")
+
+    def __init__(self, name: str):
         self.name = name
-        self.attrs = attrs
         self._t0 = 0.0
-        self._prof = None
+        self._prof = _annotation(name)
 
     def __enter__(self):
-        global _TraceAnnotation
-        if _TraceAnnotation is None:
-            from jax.profiler import TraceAnnotation
-            _TraceAnnotation = TraceAnnotation
-        self._prof = _TraceAnnotation(f"bloomrf/{self.name}")
         self._prof.__enter__()
         self._t0 = time.perf_counter()
         return self
@@ -87,18 +86,21 @@ class Span:
         self._prof.__exit__(*exc)
         _metrics.registry().histogram(
             f"obs/latency/{self.name}").observe(dur_us)
-        if _sink_file is not None:
-            rec = {"schema": TRACE_SCHEMA, "span": self.name,
-                   "ts": time.time(), "dur_us": dur_us}
-            if self.attrs:
-                rec["attrs"] = self.attrs
-            _sink_file.write(json.dumps(rec) + "\n")
-            _sink_file.flush()
         return False
 
 
-def span(name: str, **attrs):
+def _annotation(name: str):
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(PREFIX + name)
+
+
+def span(name: str):
     """Span context for op-class ``name``; a no-op singleton when off."""
-    if not _metrics.enabled():
+    if not _ENABLED:
         return NULL_SPAN
-    return Span(name, attrs)
+    if _metrics.enabled():
+        return Span(name)
+    return _annotation(name)

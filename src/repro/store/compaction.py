@@ -50,7 +50,7 @@ def merge_sorted_runs(runs: List[Run], drop_tombstones: bool = False
     entirely (bottom-level merges only)."""
     if not runs:
         raise ValueError("nothing to merge")
-    with _obs_trace.span("compaction/merge_runs", runs=len(runs)):
+    with _obs_trace.span("compaction/merge_runs"):
         return _merge_sorted_runs(runs, drop_tombstones)
 
 
@@ -136,7 +136,7 @@ def merge_filter_state(runs: List[Run], target_layout: FilterLayout,
       rebuild path to wash dead keys' bits out of the filter even when an
       OR or promote merge was available.
     """
-    with _obs_trace.span("compaction/merge_filters", runs=len(runs)):
+    with _obs_trace.span("compaction/merge_filters"):
         return _merge_filter_state(runs, target_layout, keys, build,
                                    dead_frac, purge_dead_frac, allow_promote,
                                    promote_density_slack)

@@ -227,6 +227,8 @@ class StoreStats:
     scan_filter_skips: int = 0
     scan_runs_touched: int = 0
     scan_fp_reads: int = 0          # run touched, empty slice
+    scan_mem_entries: int = 0       # memtable entries walked by scans
+    scan_rows_sliced: int = 0       # rows the touched runs' slices returned
     # data-block bytes
     bytes_read: int = 0
     bytes_not_read: int = 0         # skipped runs' data bytes
@@ -452,7 +454,7 @@ class Store:
         """Freeze the memtable into a new level-0 run."""
         if len(self.mem) == 0:
             return
-        with _obs_trace.span("store/flush", entries=len(self.mem)):
+        with _obs_trace.span("store/flush"):
             keys, vals, tombs = self.mem.sorted_entries()
             run = self._make_run(keys, vals, tombs, 0)
             run.checksums()             # cache the build-time reference
@@ -485,7 +487,7 @@ class Store:
         leaves every source run live and consistent."""
         if level >= len(self.levels) or not self.levels[level]:
             return
-        with _obs_trace.span("store/compact", level=level):
+        with _obs_trace.span("store/compact"):
             self._compact_inner(level)
 
     def _compact_inner(self, level: int) -> None:
@@ -570,27 +572,28 @@ class Store:
     def _refresh(self) -> None:
         if not self._dirty:
             return
-        self._runs = [r for lvl in self.levels for r in lvl]
-        self._flat = self._probe = None
-        self._kstate = self._fence_dev = self._quar_dev = None
-        self._kmins = np.asarray([r.kmin for r in self._runs], np.uint64)
-        self._kmaxs = np.asarray([r.kmax for r in self._runs], np.uint64)
-        self._quar = np.asarray([r.quarantined for r in self._runs], bool)
-        if self._runs and self.cfg.filter_backend == "bloomrf":
-            # a quarantined run may have no decodable state at all — stack
-            # zero lanes in its place; the quarantine mask forces its
-            # verdict to "maybe" so the zeros are never trusted
-            states = [r.state if r.state is not None
-                      else jnp.zeros(r.layout.total_u32, jnp.uint32)
-                      for r in self._runs]
-            self._flat = (states[0] if len(states) == 1
-                          else jnp.concatenate(states))
-            sizes = [r.layout.total_u32 for r in self._runs]
-            bases = tuple(int(b) for b in
-                          np.cumsum([0] + sizes[:-1], dtype=np.int64))
-            self._probe = stacked_probe(
-                tuple(r.layout for r in self._runs), bases)
-        self._dirty = False
+        with _obs_trace.span("store/refresh"):
+            self._runs = [r for lvl in self.levels for r in lvl]
+            self._flat = self._probe = None
+            self._kstate = self._fence_dev = self._quar_dev = None
+            self._kmins = np.asarray([r.kmin for r in self._runs], np.uint64)
+            self._kmaxs = np.asarray([r.kmax for r in self._runs], np.uint64)
+            self._quar = np.asarray([r.quarantined for r in self._runs], bool)
+            if self._runs and self.cfg.filter_backend == "bloomrf":
+                # a quarantined run may have no decodable state at all — stack
+                # zero lanes in its place; the quarantine mask forces its
+                # verdict to "maybe" so the zeros are never trusted
+                states = [r.state if r.state is not None
+                          else jnp.zeros(r.layout.total_u32, jnp.uint32)
+                          for r in self._runs]
+                self._flat = (states[0] if len(states) == 1
+                              else jnp.concatenate(states))
+                sizes = [r.layout.total_u32 for r in self._runs]
+                bases = tuple(int(b) for b in
+                              np.cumsum([0] + sizes[:-1], dtype=np.int64))
+                self._probe = stacked_probe(
+                    tuple(r.layout for r in self._runs), bases)
+            self._dirty = False
 
     def _quar_device(self):
         """Device quarantine mask, or None when no run is quarantined."""
@@ -615,13 +618,15 @@ class Store:
             return np.ones((len(lo), len(self._runs)), bool)
         if self.cfg.filter_backend == "bloomrf":
             if point:
-                v = self._probe.point_all(self._flat,
-                                          jnp.asarray(lo, self.kdtype))
+                out = np.asarray(self._probe.point_all(
+                    self._flat, jnp.asarray(lo, self.kdtype)))
             else:
-                v = self._probe.range_all(self._flat,
-                                          jnp.asarray(lo, self.kdtype),
-                                          jnp.asarray(hi, self.kdtype))
-            out = np.asarray(v)
+                with _obs_trace.span("store/scan/dispatch"):
+                    v = self._probe.range_all(self._flat,
+                                              jnp.asarray(lo, self.kdtype),
+                                              jnp.asarray(hi, self.kdtype))
+                with _obs_trace.span("store/scan/fetch"):
+                    out = np.asarray(v)
         else:
             cols = [r.alt.point(lo) if point else r.alt.range(lo, hi)
                     for r in self._runs]
@@ -710,12 +715,14 @@ class Store:
             self._fault("kernel.dispatch")
             dmax = np.uint64((1 << self.cfg.d) - 1)
             layouts, stack, kmin_d, kmax_d, resident = self._kernel_inputs()
-            f, t = store_scan_probe(
-                layouts, stack, kmin_d, kmax_d,
-                jnp.asarray(np.minimum(lo, dmax), jnp.uint32),
-                jnp.asarray(np.minimum(hi, dmax), jnp.uint32),
-                STORE_SCAN_TILE, resident, None, self._quar_device())
-            fence, touch = np.asarray(f), np.asarray(t)
+            with _obs_trace.span("store/scan/dispatch"):
+                f, t = store_scan_probe(
+                    layouts, stack, kmin_d, kmax_d,
+                    jnp.asarray(np.minimum(lo, dmax), jnp.uint32),
+                    jnp.asarray(np.minimum(hi, dmax), jnp.uint32),
+                    STORE_SCAN_TILE, resident, None, self._quar_device())
+            with _obs_trace.span("store/scan/fetch"):
+                fence, touch = np.asarray(f), np.asarray(t)
             # the uint32 clamp is exact for every in-domain `lo` (kmin,
             # kmax <= dmax); intervals entirely above the domain must be
             # fenced off on the host instead (kmax <= dmax < lo)
@@ -783,7 +790,7 @@ class Store:
         keys = np.atleast_1d(np.asarray(keys, np.uint64))
         if self._tuner is not None:
             self._tuner.observe_points(len(keys))
-        with _obs_trace.span("store/get", batch=len(keys)):
+        with _obs_trace.span("store/get"):
             return self._get_many_inner(keys)
 
     def _get_many_inner(self, keys: np.ndarray) -> list:
@@ -833,8 +840,11 @@ class Store:
             # host-side workload sampling (numpy histogram + reservoir);
             # the device probe dispatch below stays untouched
             self._tuner.observe_scan(los, his)
-        with _obs_trace.span("store/scan", batch=len(los)):
-            fence, touch = self._touch_masks(los, his)
+        with _obs_trace.span("store/scan"):
+            with _obs_trace.span("store/scan/prune"):
+                fence, touch = self._touch_masks(los, his)
+            # no scan changes the memtable: each one walks all of it
+            self.stats.scan_mem_entries += len(self.mem) * len(los)
             return [self._scan_one(int(lo), int(hi), fence[b], touch[b])
                     for b, (lo, hi) in enumerate(zip(los, his))]
 
@@ -844,33 +854,39 @@ class Store:
         st.scans += 1
         seen = set()
         out = {}
-        for k, v in self.mem.items():
-            if lo <= k <= hi:
-                seen.add(k)
-                if v is not TOMBSTONE:
-                    out[k] = v
-        R = len(self._runs)
-        st.scan_runs_considered += R
-        st.scan_fence_skips += int((~fence).sum())
-        st.scan_filter_skips += int((fence & ~touch).sum())
-        for r_idx, run in enumerate(self._runs):
-            if not touch[r_idx]:
-                st.bytes_not_read += run.data_bytes(self.cfg.value_bytes)
-                continue
-            st.scan_runs_touched += 1
-            st.bytes_read += run.data_bytes(self.cfg.value_bytes)
-            ks, vs, tbs = run.slice(lo, hi)
-            if len(ks) == 0:
-                st.scan_fp_reads += 1
-                continue
-            for k, v, t in zip(ks, vs, tbs):
-                k = int(k)
-                if k in seen:
-                    continue        # masked by a newer source
-                seen.add(k)
-                if not t:
-                    out[k] = v
-        return sorted(out.items())
+        span = _obs_trace.span
+        with span("store/scan/memtable"):
+            for k, v in self.mem.items():
+                if lo <= k <= hi:
+                    seen.add(k)
+                    if v is not TOMBSTONE:
+                        out[k] = v
+        with span("store/scan/runs"):
+            sliced = 0
+            R = len(self._runs)
+            st.scan_runs_considered += R
+            st.scan_fence_skips += int((~fence).sum())
+            st.scan_filter_skips += int((fence & ~touch).sum())
+            for r_idx, run in enumerate(self._runs):
+                if not touch[r_idx]:
+                    st.bytes_not_read += run.data_bytes(self.cfg.value_bytes)
+                    continue
+                st.scan_runs_touched += 1
+                st.bytes_read += run.data_bytes(self.cfg.value_bytes)
+                ks, vs, tbs = run.slice(lo, hi)
+                sliced += len(ks)
+                if len(ks) == 0:
+                    st.scan_fp_reads += 1
+                    continue
+                for k, v, t in zip(ks, vs, tbs):
+                    k = int(k)
+                    if k in seen:
+                        continue        # masked by a newer source
+                    seen.add(k)
+                    if not t:
+                        out[k] = v
+            st.scan_rows_sliced += sliced
+            return sorted(out.items())
 
     # ------------------------------------------------------------------
     # introspection / snapshots

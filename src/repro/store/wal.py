@@ -38,7 +38,6 @@ import struct
 import zlib
 from typing import Iterator, List, Optional, Tuple
 
-from ..obs import metrics as _obs_metrics
 from ..obs import trace as _obs_trace
 
 __all__ = ["Wal", "WalRecord", "WAL_FILENAME"]
@@ -90,8 +89,6 @@ class Wal:
             self._f.flush()
             if self.sync == "always":
                 os.fsync(self._f.fileno())
-        if _obs_metrics.enabled():
-            _obs_metrics.registry().counter("wal/appends").add(1)
 
     def reset(self) -> None:
         """Drop every record (post-checkpoint): the snapshot now owns them."""

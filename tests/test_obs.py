@@ -4,14 +4,15 @@ Covers the three layers and their two hard contracts:
 
 * registry units — device-scalar counter accumulation, histogram
   percentile semantics, family registration/flattening, reset;
-* span tracing — null singleton when off, latency histogram + JSONL
-  ``bloomrf-trace/v1`` records when on;
+* span tracing — null singleton when off, latency histogram when the
+  metrics plane is on too, and spans alone (the profiled run's switch)
+  touching no sampler, family or histogram;
 * FPR telemetry — both invalidation modes (insert-stream and ground
   truth), the re-probe, and the workload reservoir;
 * the **zero-overhead contract**: with observability ENABLED the jaxpr
   of a stacked range probe still contains exactly ONE gather, the fused
   store scan exactly ONE ``pallas_call``, and the jaxpr text is
-  bit-for-bit identical to the disabled run;
+  bit-for-bit identical to the disabled run and to a spans-only run;
 * durable ``StoreStats`` round-trips through ``Store.snapshot()`` /
   ``restore()``, and the real ``gates.toml`` obs gates evaluate a
   ``bloomrf-metrics/v1`` document end to end.
@@ -23,7 +24,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro
 from benchmarks import check_gates as cg
+from repro import obs
 from repro.core import basic_layout, stacked_probe
 from repro.kernels.store_scan import store_scan_probe
 from repro.obs import FprSampler, export_snapshot
@@ -35,15 +38,14 @@ from repro.store.store import StoreStats
 
 @pytest.fixture(autouse=True)
 def _fresh_obs(monkeypatch):
-    """Isolated obs state: fresh registry, disabled flag, no trace sink.
+    """Isolated obs state: fresh registry, metrics and spans off.
 
-    The registry and enabled flag are process globals — tests must not
+    The registry and both switches are process globals — tests must not
     leak counters or the enabled state into each other (or into the
     rest of the suite, which pins obs-off jaxprs elsewhere)."""
     monkeypatch.setattr(obs_metrics, "_REGISTRY", obs_metrics.MetricsRegistry())
     monkeypatch.setattr(obs_metrics, "_ENABLED", False)
-    yield
-    obs_trace.set_trace_sink(None)
+    monkeypatch.setattr(obs_trace, "_ENABLED", False)
 
 
 # ---------------------------------------------------------------------------
@@ -128,24 +130,107 @@ def test_span_is_null_singleton_when_disabled():
     assert "obs/latency/unit/op" not in obs_metrics.registry().snapshot()
 
 
-def test_span_feeds_latency_histogram_and_jsonl_sink(tmp_path):
-    obs_metrics.enable()
-    sink = tmp_path / "trace.jsonl"
-    obs_trace.set_trace_sink(str(sink))
-    with obs_trace.span("unit/op", runs=3):
+def test_span_feeds_latency_histogram_and_jsonl_sink():
+    obs.enable()
+    with obs_trace.span("unit/op"):
         pass
     with obs_trace.span("unit/op"):
         pass
-    obs_trace.set_trace_sink(None)
     snap = obs_metrics.registry().snapshot()
     assert snap["obs/latency/unit/op"]["count"] == 2
-    recs = [json.loads(ln) for ln in sink.read_text().splitlines()]
-    assert len(recs) == 2
-    assert recs[0]["schema"] == "bloomrf-trace/v1"
-    assert recs[0]["span"] == "unit/op"
-    assert recs[0]["dur_us"] >= 0.0
-    assert recs[0]["attrs"] == {"runs": 3}
-    assert "attrs" not in recs[1]
+    assert snap["obs/latency/unit/op"]["mean"] >= 0.0
+
+
+def test_spans_and_metrics_have_separate_switches():
+    obs_metrics.enable()                  # metrics alone: spans stay off
+    assert obs_trace.span("unit/op") is obs_trace.NULL_SPAN
+    obs_metrics.disable()
+    obs_trace.enable()                    # spans alone: a bare annotation
+    sp = obs_trace.span("unit/op")
+    assert isinstance(sp, jax.profiler.TraceAnnotation)
+    with sp:
+        pass
+    assert obs_metrics.registry().snapshot() == {}
+    obs.disable()
+    assert not obs_trace.enabled() and not obs_metrics.enabled()
+
+
+def _spans_only_answers(on: bool, rng_seed: int):
+    """A filter's range/point answers and a store's scans, with spans on
+    alone or everything off."""
+    (obs_trace.enable if on else obs_trace.disable)()
+    rng = np.random.default_rng(rng_seed)
+    keys = rng.integers(0, 1 << 32, 2000, dtype=np.uint64)
+    f = repro.open_filter(repro.FilterSpec(dtype="u64", n=2000,
+                                           bits_per_key=12, backend="xla"))
+    f.insert(keys)
+    lo = np.concatenate([keys[:64], rng.integers(0, 1 << 40, 64,
+                                                 dtype=np.uint64)])
+    hi = lo + np.uint64(100)
+    st = repro.open_filter(repro.FilterSpec(
+        dtype="u64", placement="store", memtable_limit=300, level0_runs=2,
+        backend="xla"))
+    for k in keys[:1000].tolist():
+        st.put(k, k)
+    return (f.range(lo, hi), f.point(lo), st.scan_many(lo, hi), f, st)
+
+
+def test_spans_alone_build_no_sampler_family_or_histogram():
+    on = _spans_only_answers(True, 11)
+    snap = obs_metrics.registry().snapshot()
+    assert snap == {}                     # no family, no histogram
+    assert on[3]._fpr is None and on[4]._fpr is None
+    assert on[4].stats.scans == 128
+    off = _spans_only_answers(False, 11)
+    np.testing.assert_array_equal(on[0], off[0])
+    np.testing.assert_array_equal(on[1], off[1])
+    assert on[2] == off[2]
+
+
+def _latency_counts() -> dict:
+    return {k[len("obs/latency/"):]: v["count"]
+            for k, v in obs_metrics.registry().snapshot().items()
+            if k.startswith("obs/latency/")}
+
+
+@pytest.mark.parametrize("placement", ["single", "tenant"])
+def test_facade_probe_spans_hold_the_encode_on_every_handle(placement):
+    """`facade/range` and `facade/point` time the same work on every
+    filter handle: the encode is a `facade/encode` child of each."""
+    obs.enable()
+    keys = np.arange(40, dtype=np.uint64) * 7 + 3
+    if placement == "tenant":
+        h = repro.open_filter(repro.FilterSpec(
+            dtype="u32", n=64, placement="tenant", tenants=2, shards=2))
+        h.insert(1, keys)
+        assert h.range(1, keys, keys + 1).all() and h.point(1, keys).all()
+    else:
+        h = repro.open_filter(repro.FilterSpec(dtype="u32", n=64,
+                                               backend="xla"))
+        h.insert(keys)
+        assert h.range(keys, keys + 1).all() and h.point(keys).all()
+    counts = _latency_counts()
+    assert counts["facade/encode"] == 2
+    assert counts["facade/range"] == counts["facade/point"] == 1
+
+
+def test_store_point_probes_carry_no_scan_plane_spans():
+    """The pruning call's dispatch and fetch spans belong to scans; a
+    batch of gets goes through the same call without them."""
+    obs.enable()
+    st = repro.open_filter(repro.FilterSpec(
+        dtype="u64", placement="store", memtable_limit=64, level0_runs=2,
+        backend="xla"))
+    for k in range(200):
+        st.put(k * 3, k)
+    before = _latency_counts()
+    assert st.get_many(np.arange(0, 30, 3, dtype=np.uint64))[1] == 1
+    counts = _latency_counts()
+    grew = {k for k, n in counts.items() if n != before.get(k, 0)}
+    assert grew <= {"facade/get", "store/get", "store/refresh"}
+    st.scan_many(np.asarray([0, 30], np.uint64), np.asarray([9, 90], np.uint64))
+    counts = _latency_counts()
+    assert counts["store/scan/dispatch"] == counts["store/scan/fetch"] == 1
 
 
 # ---------------------------------------------------------------------------
@@ -231,7 +316,7 @@ def _stacked_case(rng):
 
 
 def test_stacked_probe_one_gather_with_obs_enabled(rng):
-    obs_metrics.enable()
+    obs.enable()
     sp, flat = _stacked_case(rng)
     lo = jnp.zeros(64, jnp.uint32)
     hi = jnp.full(64, 9999, jnp.uint32)
@@ -242,19 +327,24 @@ def test_stacked_probe_one_gather_with_obs_enabled(rng):
 
 
 def test_jaxpr_text_identical_obs_on_vs_off(rng):
-    """jax.named_scope adds NO equations: the traces must be bit-equal."""
+    """jax.named_scope adds NO equations: the traces must be bit-equal,
+    with everything on and with the spans on alone."""
     sp, flat = _stacked_case(rng)
     lo = jnp.zeros(64, jnp.uint32)
     hi = jnp.full(64, 9999, jnp.uint32)
-    obs_metrics.disable()
+    obs.disable()
     off = str(jax.make_jaxpr(sp._range_all)(flat, lo, hi))
-    obs_metrics.enable()
+    obs.enable()
     on = str(jax.make_jaxpr(sp._range_all)(flat, lo, hi))
     assert on == off
+    obs.disable()
+    obs_trace.enable()
+    spans_only = str(jax.make_jaxpr(sp._range_all)(flat, lo, hi))
+    assert spans_only == off
 
 
 def test_store_scan_one_pallas_call_with_obs_enabled(rng):
-    obs_metrics.enable()
+    obs.enable()
     st = Store(StoreConfig(d=32, memtable_limit=300, level0_runs=3,
                            scan_backend="kernel"))
     st.register_obs()
