@@ -6,8 +6,8 @@ This package reproduces that workload standalone: a mutable
 :class:`Memtable` flushes into immutable sorted :class:`Run`s, each carrying
 a bloomRF filter block plus min/max fences; leveled compaction merges runs
 and merges/rebuilds their filter state; and the read path batch-probes all
-live runs' filters with ONE fused gather over the stacked state
-(``core.engine.StackedProbe``) before touching any run's data.
+live runs' filters with ONE fused gather over the padded stacked state
+(``store.scan_plane``, layouts as data) before touching any run's data.
 """
 from .compaction import merge_filter_state, merge_sorted_runs
 from .faults import FaultPlan, InjectedCrash, fault_seed_from_env

@@ -10,13 +10,15 @@ next level's run) downward — same-class filter blocks merge with a single
 insert path (``compaction.merge_filter_state``).
 
 Read path: ``get``/``scan`` first consult the memtable, then probe **all**
-live runs' filters at once — the per-run states are concatenated into one
-flat lane vector and probed through ``core.engine.StackedProbe``, so a
-scan over R runs costs exactly ONE fused gather over the stacked filter
-state regardless of R or the mix of capacity classes (jaxpr-asserted in
-the test suite).  Only runs whose fences overlap *and* whose filter says
-"maybe" have their data blocks touched; :class:`StoreStats` counts what
-the filters saved (skips, false-positive reads, bytes not read).
+live runs' filters at once — the per-run states are copied into one
+padded flat lane vector and probed through ``store.scan_plane``, which
+takes the runs' layouts as data, so a scan over R runs costs exactly ONE
+fused gather over the stacked filter state regardless of R or the mix of
+capacity classes (jaxpr-asserted in the test suite), and the probe
+programs survive flushes and compactions.  Only runs whose fences
+overlap *and* whose filter says "maybe" have their data blocks touched;
+:class:`StoreStats` counts what the filters saved (skips, false-positive
+reads, bytes not read).
 
 Filters are insert-only at write time: a delete writes a tombstone
 *entry* whose key is inserted like any other, so newer tombstones are
@@ -60,7 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import basic_layout, key_dtype_for
-from ..core.engine import _filter_for_layout, stacked_probe
+from ..core.engine import _filter_for_layout
 from ..kernels import FilterOps, read_vmem_budget_u32
 from ..kernels.store_scan import DEFAULT_TILE as STORE_SCAN_TILE
 from ..kernels.store_scan import build_run_stack, store_scan_probe
@@ -71,6 +73,7 @@ from .faults import FaultPlan
 from .integrity import (MANIFEST_FILENAME, atomic_write_bytes, crc32_bytes,
                         read_manifest, write_manifest)
 from .memtable import TOMBSTONE, Memtable
+from . import scan_plane
 from .run import Run
 from .wal import WAL_FILENAME, Wal
 
@@ -111,7 +114,7 @@ class StoreConfig:
                                     # | "xla" — "kernel" runs the fused
                                     # store-scan Pallas megakernel
                                     # (kernels/store_scan.py), "xla" the
-                                    # StackedProbe.touch_all reference,
+                                    # scan_plane.touch_all reference,
                                     # "auto" picks the kernel on TPU only
                                     # (interpret-mode Pallas is slow on CPU)
     use_insert_kernels: Optional[bool] = None  # filter builds through the
@@ -237,6 +240,10 @@ class StoreStats:
     wal_replayed: int = 0           # records recovered at the last open
     degraded_probes: int = 0        # (query, run) cells answered fence-only
                                     # because the run is quarantined
+    # the XLA scan plane (store/scan_plane.py), per stack it probes
+    scan_plane_restacks: int = 0    # stacks served by the resident programs
+    scan_plane_grows: int = 0       # stacks that outgrew the plane's shape
+                                    # (its first stack too): new programs
 
     # Counters that survive Store.snapshot()/restore(): the write-path
     # history that produced the snapshotted runs.  Read-path counters,
@@ -297,8 +304,8 @@ class Store:
         self.faults = faults                  # fault-injection seams (tests)
         self._ops: dict = {}                  # FilterOps per layout
         self._runs: List[Run] = []
-        self._flat = None                     # stacked filter lanes
-        self._probe = None
+        self._stack = None                    # padded device stack (scan plane)
+        self._plane_shape = None              # the plane's shape; only grows
         self._kmins = self._kmaxs = None      # per-run fences, np.uint64 (R,)
         self._quar = None                     # per-run quarantine mask (R,)
         self._quar_dev = None                 # lazy device copy of _quar
@@ -574,26 +581,49 @@ class Store:
             return
         with _obs_trace.span("store/refresh"):
             self._runs = [r for lvl in self.levels for r in lvl]
-            self._flat = self._probe = None
-            self._kstate = self._fence_dev = self._quar_dev = None
+            self._stack = self._kstate = None
+            self._fence_dev = self._quar_dev = None
             self._kmins = np.asarray([r.kmin for r in self._runs], np.uint64)
             self._kmaxs = np.asarray([r.kmax for r in self._runs], np.uint64)
             self._quar = np.asarray([r.quarantined for r in self._runs], bool)
-            if self._runs and self.cfg.filter_backend == "bloomrf":
-                # a quarantined run may have no decodable state at all — stack
-                # zero lanes in its place; the quarantine mask forces its
-                # verdict to "maybe" so the zeros are never trusted
-                states = [r.state if r.state is not None
-                          else jnp.zeros(r.layout.total_u32, jnp.uint32)
-                          for r in self._runs]
-                self._flat = (states[0] if len(states) == 1
-                              else jnp.concatenate(states))
-                sizes = [r.layout.total_u32 for r in self._runs]
-                bases = tuple(int(b) for b in
-                              np.cumsum([0] + sizes[:-1], dtype=np.int64))
-                self._probe = stacked_probe(
-                    tuple(r.layout for r in self._runs), bases)
             self._dirty = False
+
+    def _plane_floor(self) -> scan_plane.PlaneShape:
+        """The most the capacity-class ladder stacks at today's depth:
+        ``level0_runs`` class-0 runs and one run per lower level at its
+        largest class.  Sizing the plane to it keeps flushes and
+        compactions inside a level on one compiled shape; only a new level
+        (or a stack off the ladder) grows it."""
+        below = len(self.levels) - 1
+        c0 = self.class_layout(1)
+        lanes = self.cfg.level0_runs * c0.total_u32 + sum(
+            self.class_layout(self.class_capacity(lv)).total_u32
+            for lv in range(1, below + 1))
+        return scan_plane.PlaneShape(self.cfg.level0_runs + below, lanes + 1,
+                                     c0.k, 1)
+
+    def _run_stack(self) -> scan_plane.RunStack:
+        """The XLA plane's padded device stack, built once per refresh.
+
+        A quarantined run may have no decodable state at all — its lanes
+        stay zero and the quarantine mask forces its verdict to "maybe",
+        so the zeros are never trusted."""
+        if self._stack is None:
+            with _obs_trace.span("store/refresh"):
+                layouts = [r.layout for r in self._runs]
+                shape = scan_plane.PlaneShape.of(layouts).cover(
+                    self._plane_floor())
+                if self._plane_shape is not None:
+                    shape = shape.cover(self._plane_shape)
+                if shape == self._plane_shape:
+                    self.stats.scan_plane_restacks += 1
+                else:
+                    self.stats.scan_plane_grows += 1
+                self._plane_shape = shape
+                self._stack = scan_plane.stack_runs(
+                    layouts, [r.state for r in self._runs], self._kmins,
+                    self._kmaxs, self._quar, shape)
+        return self._stack
 
     def _quar_device(self):
         """Device quarantine mask, or None when no run is quarantined."""
@@ -617,16 +647,20 @@ class Store:
         if self.cfg.filter_backend == "none":
             return np.ones((len(lo), len(self._runs)), bool)
         if self.cfg.filter_backend == "bloomrf":
+            # the plane answers (B, rows) with the padded rows False; the
+            # live rows are the first R columns
+            stack = self._run_stack()
             if point:
-                out = np.asarray(self._probe.point_all(
-                    self._flat, jnp.asarray(lo, self.kdtype)))
+                out = np.asarray(scan_plane.point_all(
+                    stack, jnp.asarray(lo, self.kdtype)))
             else:
                 with _obs_trace.span("store/scan/dispatch"):
-                    v = self._probe.range_all(self._flat,
-                                              jnp.asarray(lo, self.kdtype),
-                                              jnp.asarray(hi, self.kdtype))
+                    v = scan_plane.range_all(stack,
+                                             jnp.asarray(lo, self.kdtype),
+                                             jnp.asarray(hi, self.kdtype))
                 with _obs_trace.span("store/scan/fetch"):
                     out = np.asarray(v)
+            out = out[:, :len(self._runs)]
         else:
             cols = [r.alt.point(lo) if point else r.alt.range(lo, hi)
                     for r in self._runs]
@@ -744,8 +778,9 @@ class Store:
         (``scan_many`` handles out-of-domain clamping on the host).
 
         One fused megakernel call in ``kernel`` mode; the jit'd
-        ``StackedProbe.touch_all`` (still one fused gather) in ``xla``
-        mode; fence-only verdicts for ``filter_backend="none"``."""
+        ``scan_plane.touch_all`` (still one fused gather) in ``xla``
+        mode, its padded rows sliced off on the device; fence-only
+        verdicts for ``filter_backend="none"``."""
         self._refresh()
         if _obs_metrics.enabled():
             # host-side batch odometer only: the dispatch stays async and
@@ -762,15 +797,16 @@ class Store:
             return store_scan_probe(layouts, stack, kmin_d, kmax_d,
                                     lo, hi, STORE_SCAN_TILE, resident, None,
                                     self._quar_device())
+        lo = jnp.asarray(lo, self.kdtype)
+        hi = jnp.asarray(hi, self.kdtype)
+        if self.cfg.filter_backend == "bloomrf":
+            fence, touch = scan_plane.touch_all(self._run_stack(), lo, hi)
+            R = len(self._runs)
+            return fence[:, :R], touch[:, :R]
         if self._fence_dev is None:
             self._fence_dev = (jnp.asarray(self._kmins, self.kdtype),
                                jnp.asarray(self._kmaxs, self.kdtype))
         kmin_d, kmax_d = self._fence_dev
-        lo = jnp.asarray(lo, self.kdtype)
-        hi = jnp.asarray(hi, self.kdtype)
-        if self.cfg.filter_backend == "bloomrf":
-            return self._probe.touch_all(self._flat, kmin_d, kmax_d, lo, hi,
-                                         self._quar_device())
         if self.cfg.filter_backend == "none":
             fence, touch = _fence_touch_device(kmin_d, kmax_d, lo, hi)
             return fence, touch

@@ -151,9 +151,11 @@ def test_store_placement_one_gather_jaxpr():
 
     lo = jnp.zeros(16, store.kdtype)
     hi = lo + 77
-    jx = jax.make_jaxpr(store._probe._range_all)(store._flat, lo, hi)
+    from repro.store import scan_plane
+
+    jx = jax.make_jaxpr(scan_plane.range_all)(store._run_stack(), lo, hi)
     assert _count_gathers(jx.jaxpr) == 1, jx.pretty_print()
-    jp = jax.make_jaxpr(store._probe._point_all)(store._flat, lo)
+    jp = jax.make_jaxpr(scan_plane.point_all)(store._run_stack(), lo)
     assert _count_gathers(jp.jaxpr) == 1
 
 

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from repro.core import basic_layout
-from repro.store import Run, Store, StoreConfig, merge_sorted_runs
+from repro.store import Run, Store, StoreConfig, merge_sorted_runs, scan_plane
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
@@ -167,10 +167,13 @@ def test_scan_over_8_runs_is_one_gather(rng):
     assert len({r.layout for r in runs}) >= 2   # mixed capacity classes
     lo = jnp.zeros(64, jnp.uint32)
     hi = jnp.full(64, 1 << 20, jnp.uint32)
-    jaxpr = jax.make_jaxpr(st._probe._range_all)(st._flat, lo, hi)
+    stack = st._run_stack()
+    jaxpr = jax.make_jaxpr(scan_plane.range_all)(stack, lo, hi)
     assert _count_gathers(jaxpr.jaxpr) == 1, jaxpr.pretty_print()
-    jaxpr_p = jax.make_jaxpr(st._probe._point_all)(st._flat, lo)
+    jaxpr_p = jax.make_jaxpr(scan_plane.point_all)(stack, lo)
     assert _count_gathers(jaxpr_p.jaxpr) == 1
+    jaxpr_t = jax.make_jaxpr(scan_plane.touch_all)(stack, lo, hi)
+    assert _count_gathers(jaxpr_t.jaxpr) == 1
 
 
 def test_stacked_probe_matches_per_run_probes(rng):

@@ -21,7 +21,7 @@ from repro.core import basic_layout
 from repro.core.engine import _filter_for_layout
 from repro.core.tuning import advise
 from repro.obs.fpr import LOG2_BUCKETS, SAMPLE_FIELDS, FprSampler
-from repro.store import Store, StoreConfig
+from repro.store import Store, StoreConfig, scan_plane
 from repro.tune import (AdaptiveTuner, Hysteresis, WorkloadModel,
                         candidate_layouts, cross_check, fit_workload,
                         score_layout, solve)
@@ -599,7 +599,8 @@ def test_retuned_stack_keeps_probe_plane_invariants():
     # one gather through the stacked point/range probe plane
     lo = jnp.asarray(np.arange(64), jnp.uint32)
     jx = jax.make_jaxpr(
-        lambda flat, a: st._probe.range_all(flat, a, a))(st._flat, lo)
+        lambda stack, a: scan_plane.range_all(stack, a, a))(
+        st._run_stack(), lo)
     assert _count_gathers(jx.jaxpr) == 1
     # one pallas_call through the scan megakernel
     layouts, stack, kmin_d, kmax_d, resident = st._kernel_inputs()
